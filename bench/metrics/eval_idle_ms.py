@@ -1,0 +1,7 @@
+"""Idle time of the fullest device per window while the host was inside
+``engine.evaluate``, the innermost engine span over the idle instant (ms)."""
+from harness import scopes
+
+
+def read(ctx):
+    return scopes.idle_ms(ctx["trace"], "eval_idle_ms")

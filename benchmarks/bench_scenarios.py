@@ -105,7 +105,6 @@ def bench_one(name: str, n: int, args, devices: int = 1,
         # slot table / parameter plane was real work
         "padded_slot_frac": occ["padded_slot_frac"],
         "owned_plane_frac": occ["owned_plane_frac"],
-        "effective_flops_utilization": occ["effective_flops_utilization"],
         "scheduled_per_round": [m.n_scheduled for m in res.history],
         "handovers": int(sum(m.n_handover for m in res.history)),
         "final_loss": float(res.history[-1].loss),

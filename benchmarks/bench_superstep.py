@@ -102,7 +102,6 @@ def bench_variant(n, args, superstep, schedule, slot_capacity,
         # occupancy accounting (DESIGN.md §12)
         "padded_slot_frac": occ["padded_slot_frac"],
         "owned_plane_frac": occ["owned_plane_frac"],
-        "effective_flops_utilization": occ["effective_flops_utilization"],
         "handovers": int(sum(m.n_handover for m in hist)),
         "final_loss": float(hist[-1].loss),
     }

@@ -242,7 +242,8 @@ def run(spec: ExperimentSpec, *,
             mode=engine.mode, n_rsus=engine.n_rsus,
             compile_fallbacks=engine.programs.compile_fallbacks,
             superstep_layout=engine.programs.layout,
-            occupancy=engine.occupancy_stats())
+            occupancy=engine.occupancy_stats(),
+            counters=dict(engine.counters))
         mesh = engine.fleet_mesh
     else:
         diagnostics.update(mode=engine.engine.mode, n_rsus=1)

@@ -418,15 +418,19 @@ def make_eval_fn(model: UnitModel):
     if fn is None:
         @jax.jit
         def fn(units, head, x, y):
-            feats = model.apply_units(units, x, 0)
-            logits = model.head_predict(head, feats)
-            return jnp.sum(jnp.argmax(logits, -1) == y)
+            with jax.named_scope("asfl.eval"):
+                feats = model.apply_units(units, x, 0)
+                logits = model.head_predict(head, feats)
+                return jnp.sum(jnp.argmax(logits, -1) == y)
         model._eval_fn = fn
     return fn
 
 
+EVAL_BATCH = 256
+
+
 def evaluate(model: UnitModel, units, head, test: Dict[str, jnp.ndarray],
-             batch: int = 256) -> float:
+             batch: int = EVAL_BATCH) -> float:
     fn = make_eval_fn(model)
     n = test["labels"].shape[0]
     correct = []
@@ -1633,6 +1637,13 @@ class ScenarioEngine:
                                                len(self.clients))
         self._sync_count = 0
         self.history: List[ScenarioRoundMetrics] = []
+        # what the engine did since the last reset, counted where it
+        # happens: windows and rounds run, programs enqueued (super-steps,
+        # global-model copies or unravels, eval batches), blocking device
+        # reads (pulled scan outputs, eval batch counts), evaluations
+        self.counters: Dict[str, int] = dict.fromkeys(
+            ("windows", "rounds", "dispatches", "host_syncs",
+             "evaluations"), 0)
 
     # ---- staging ------------------------------------------------------
     def _nb_ep(self) -> Tuple[int, int]:
@@ -1709,8 +1720,7 @@ class ScenarioEngine:
         (padded grid for dense/sequential, compacted capacity for
         ragged+parallel); ``mean_occupied_slots`` averages the scheduled
         counts over the recorded history; ``owned_plane_frac`` is the
-        client-plane prefix fraction (1.0 dense); the effective-FLOPs
-        utilization is the occupied share of executed slot fwd/bwd work."""
+        client-plane prefix fraction (1.0 dense)."""
         pg = self.programs
         horizon = max(int(self.cfg.rounds), 1)
         cap = self._capacity(horizon)
@@ -1730,7 +1740,6 @@ class ScenarioEngine:
             "padded_slot_frac": float(1.0 - util),
             "owned_plane_frac": float(pg.plane_width
                                       / max(pg.n_params, 1)),
-            "effective_flops_utilization": float(util),
         }
 
     def _window_xs(self, rnd0: int, k: int):
@@ -1797,14 +1806,26 @@ class ScenarioEngine:
     def run_superstep(self, rnd0: int, k: int) -> List[ScenarioRoundMetrics]:
         """Execute rounds [rnd0, rnd0+k) as ONE compiled program and return
         their metrics.  The previous carry is donated; per-round arrays come
-        back as scan outputs and are pulled to the host once."""
+        back as scan outputs and are pulled to the host once.
+
+        The host work is annotated for JAX's profiler: an ``engine.window``
+        span (stat ``rnd0``) around ``engine.stage``, ``engine.dispatch``,
+        ``engine.pull``, ``engine.global_model``, ``engine.metrics`` and
+        ``engine.evaluate``; ``counters`` counts what the window did."""
+        with jax.profiler.TraceAnnotation("engine.window", rnd0=rnd0):
+            return self._run_window(rnd0, k)
+
+    def _run_window(self, rnd0: int, k: int) -> List[ScenarioRoundMetrics]:
         horizon = max(self.cfg.rounds, rnd0 + k)
         cap = self._capacity(horizon)
         sig = self.programs.signature(k, cap, self._total_slots(horizon))
         fn = self.programs.get(sig)
-        xs = self._xs_stage.take((rnd0, k),
-                                 lambda: self._window_xs(rnd0, k))
-        carry, ys = fn(self._carry, xs)            # async dispatch
+        with jax.profiler.TraceAnnotation("engine.stage"):
+            xs = self._xs_stage.take((rnd0, k),
+                                     lambda: self._window_xs(rnd0, k))
+        with jax.profiler.TraceAnnotation("engine.dispatch"):
+            carry, ys = fn(self._carry, xs)        # async dispatch
+        self.counters["dispatches"] += 1
         # double-buffered staging (DESIGN.md §14): while the dispatched
         # window trains on device, build the NEXT window's batch/mobility
         # arrays and start their transfers — newly arrived vehicles' shards
@@ -1814,49 +1835,65 @@ class ScenarioEngine:
         if nxt < self.cfg.rounds:
             kk = min(max(int(self.cfg.superstep or 1), 1),
                      self.cfg.rounds - nxt)
-            self._xs_stage.stage((nxt, kk),
-                                 lambda: self._window_xs(nxt, kk))
-        ys = jax.tree.map(np.asarray, ys)          # ONE host sync per window
-        if int(ys["counts"].max(initial=0)) > cap:
-            # raise BEFORE committing the window: the window silently
-            # dropped overflow vehicles, so its carry must not become
-            # engine state (the donated previous carry is gone — the engine
-            # needs reset() — but nothing masquerades as valid training)
-            raise RuntimeError(
-                f"per-RSU cohort exceeded slot capacity {cap}; traced vs "
-                f"host association disagree — raise the capacity margin "
-                f"and reset() the engine")
-        if sig.slots and int(ys["counts"].sum(axis=-1).max(initial=0)) \
-                > sig.slots:
-            # the ragged layout's compacted axis silently truncates the
-            # sorted slot order past its capacity — same contract as the
-            # per-RSU check above
-            raise RuntimeError(
-                f"fleet-wide occupied slots exceeded the compacted "
-                f"capacity {sig.slots}; traced vs host association "
-                f"disagree — raise the capacity margin and reset() the "
-                f"engine")
+            with jax.profiler.TraceAnnotation("engine.stage"):
+                self._xs_stage.stage((nxt, kk),
+                                     lambda: self._window_xs(nxt, kk))
+        with jax.profiler.TraceAnnotation("engine.pull"):
+            ys = jax.tree.map(np.asarray, ys)      # ONE host sync per window
+            self.counters["host_syncs"] += len(jax.tree.leaves(ys))
+            if int(ys["counts"].max(initial=0)) > cap:
+                # raise BEFORE committing the window: the window silently
+                # dropped overflow vehicles, so its carry must not become
+                # engine state (the donated previous carry is gone — the
+                # engine needs reset() — but nothing masquerades as valid
+                # training)
+                raise RuntimeError(
+                    f"per-RSU cohort exceeded slot capacity {cap}; traced "
+                    f"vs host association disagree — raise the capacity "
+                    f"margin and reset() the engine")
+            if sig.slots and int(ys["counts"].sum(axis=-1).max(initial=0)) \
+                    > sig.slots:
+                # the ragged layout's compacted axis silently truncates the
+                # sorted slot order past its capacity — same contract as
+                # the per-RSU check above
+                raise RuntimeError(
+                    f"fleet-wide occupied slots exceeded the compacted "
+                    f"capacity {sig.slots}; traced vs host association "
+                    f"disagree — raise the capacity margin and reset() the "
+                    f"engine")
         self._carry = carry
-        self.units, self.head = self.programs.global_model(carry)
+        with jax.profiler.TraceAnnotation("engine.global_model"):
+            self.units, self.head = self.programs.global_model(carry)
+        self.counters["dispatches"] += self.programs.global_model_programs
         out = []
         eval_due, last_synced = False, None
-        for i in range(k):
-            out.append(self._round_metrics(rnd0 + i, i, ys))
-            if (rnd0 + i + 1) % self.cloud_sync_every == 0:
-                # evaluate every eval_every-th cloud sync (the global model
-                # only changes at syncs) — counted here on the host, since
-                # the fused window keeps no per-round model snapshots
-                ev = self.cfg.eval_every
-                if ev and self._sync_count % ev == 0:
-                    eval_due = True
-                self._sync_count += 1
-                last_synced = i
+        with jax.profiler.TraceAnnotation("engine.metrics"):
+            for i in range(k):
+                out.append(self._round_metrics(rnd0 + i, i, ys))
+                if (rnd0 + i + 1) % self.cloud_sync_every == 0:
+                    # evaluate every eval_every-th cloud sync (the global
+                    # model only changes at syncs) — counted here on the
+                    # host, since the fused window keeps no per-round model
+                    # snapshots
+                    ev = self.cfg.eval_every
+                    if ev and self._sync_count % ev == 0:
+                        eval_due = True
+                    self._sync_count += 1
+                    last_synced = i
+        self.counters["windows"] += 1
+        self.counters["rounds"] += k
         if eval_due and last_synced is not None:
             # the current global IS the last synced round's model (later
             # rounds trained edges but did not merge), so attaching the
             # score there is exact; K=1 reproduces the per-round schedule
-            out[last_synced].test_acc = evaluate(
-                self.model, self.units, self.head, self.test)
+            with jax.profiler.TraceAnnotation("engine.evaluate"):
+                out[last_synced].test_acc = evaluate(
+                    self.model, self.units, self.head, self.test)
+            # one program and one host read per eval batch
+            batches = -(-int(self.test["labels"].shape[0]) // EVAL_BATCH)
+            self.counters["dispatches"] += batches
+            self.counters["host_syncs"] += batches
+            self.counters["evaluations"] += 1
         return out
 
     def _round_metrics(self, rnd: int, i: int, ys) -> ScenarioRoundMetrics:

@@ -316,6 +316,11 @@ class SuperStepPrograms:
                 f"superstep engine requires uniform float32 params, got "
                 f"{flat.dtype} after ravel")
         self.n_params = int(flat.size)
+        # programs global_model() enqueues per call: a copy per leaf of the
+        # global tree (sequential) or one unravel of the flat plane
+        self.global_model_programs = (len(jax.tree.leaves(template))
+                                      if self.schedule == "sequential"
+                                      else 1)
         ids = {"units": [jax.tree.map(
             lambda a, _u=u: np.full(np.shape(a), _u, np.int32), ut)
             for u, ut in enumerate(units)],
@@ -666,16 +671,17 @@ class SuperStepPrograms:
                     # skipping their candidates changes no selected value
                     continue
                 is_b = cut_j == (u + 1)
-                if ef:
-                    sz = int(np.prod(bshapes[u]))
-                    yb, r2 = compression.wire_boundary(
-                        h, res_j[:sz].reshape(bshapes[u]), wire_k)
-                    r = jnp.where(is_b,
-                                  jnp.pad(r2.reshape(-1),
-                                          (0, res_size - sz)), r)
-                else:
-                    yb = compression.quant_boundary(h)
-                h = jnp.where(is_b, yb, h)
+                with jax.named_scope("asfl.wire"):
+                    if ef:
+                        sz = int(np.prod(bshapes[u]))
+                        yb, r2 = compression.wire_boundary(
+                            h, res_j[:sz].reshape(bshapes[u]), wire_k)
+                        r = jnp.where(is_b,
+                                      jnp.pad(r2.reshape(-1),
+                                              (0, res_size - sz)), r)
+                    else:
+                        yb = compression.quant_boundary(h)
+                    h = jnp.where(is_b, yb, h)
             feats = model.apply_units([units[U - 1]], h, U - 1)
             loss, _ = model.head_loss(head, feats, y)
             return loss, (r if ef else jnp.zeros((0,), jnp.float32))
@@ -695,40 +701,45 @@ class SuperStepPrograms:
                 cu_j, m_j, cut_j, act, idx_j, res_j = inp
             else:
                 cu_j, m_j, cut_j, act, idx_j = inp
-            x = images[m_j][idx_j]
-            y = labels[m_j][idx_j]
+            with jax.named_scope("asfl.batch"):
+                x = images[m_j][idx_j]
+                y = labels[m_j][idx_j]
             # units at or past the max-cut bucket have no client replica in
             # the ragged layout (CU < U): no cut can reach them, so the
             # server copy is the effective parameter unconditionally — the
             # same value the dense select produces (its replica is never
             # updated there), hence bit-for-bit across layouts
-            eff = [_select(u < cut_j, cu_j[u], sv["units"][u])
-                   for u in range(CU)] \
-                + [sv["units"][u] for u in range(CU, U)]
-            if wire == "none":
-                (loss, _), (g_units, g_head) = jax.value_and_grad(
-                    loss_fn, argnums=(0, 1), has_aux=True)(
-                        eff, sv["head"], x, y)
-            else:
-                (loss, res_new), (g_units, g_head) = jax.value_and_grad(
-                    wire_loss, argnums=(0, 1), has_aux=True)(
-                        eff, sv["head"], x, y, cut_j,
-                        res_j if ef else None)
-            keep_s = [act & (u >= cut_j) for u in range(U)]
-            g_sv = {"units": [_select(u >= cut_j, g_units[u],
-                                      jax.tree.map(jnp.zeros_like,
-                                                   g_units[u]))
-                              for u in range(U)],
-                    "head": g_head}
-            upd, so2 = opt.update(g_sv, so, sv)
-            sv2 = optim.apply_updates(sv, upd)
-            sv3 = {"units": [_select(keep_s[u], sv2["units"][u],
-                                     sv["units"][u]) for u in range(U)],
-                   "head": _select(act, sv2["head"], sv["head"])}
-            so3 = _sel_server_state(so2, so, keep_s, act)
-            ys = (list(g_units[:CU]), jnp.where(act, loss, 0.0))
+            with jax.named_scope("asfl.server_update"):
+                eff = [_select(u < cut_j, cu_j[u], sv["units"][u])
+                       for u in range(CU)] \
+                    + [sv["units"][u] for u in range(CU, U)]
+            with jax.named_scope("asfl.grad"):
+                if wire == "none":
+                    (loss, _), (g_units, g_head) = jax.value_and_grad(
+                        loss_fn, argnums=(0, 1), has_aux=True)(
+                            eff, sv["head"], x, y)
+                else:
+                    (loss, res_new), (g_units, g_head) = jax.value_and_grad(
+                        wire_loss, argnums=(0, 1), has_aux=True)(
+                            eff, sv["head"], x, y, cut_j,
+                            res_j if ef else None)
+            with jax.named_scope("asfl.server_update"):
+                keep_s = [act & (u >= cut_j) for u in range(U)]
+                g_sv = {"units": [_select(u >= cut_j, g_units[u],
+                                          jax.tree.map(jnp.zeros_like,
+                                                       g_units[u]))
+                                  for u in range(U)],
+                        "head": g_head}
+                upd, so2 = opt.update(g_sv, so, sv)
+                sv2 = optim.apply_updates(sv, upd)
+                sv3 = {"units": [_select(keep_s[u], sv2["units"][u],
+                                         sv["units"][u]) for u in range(U)],
+                       "head": _select(act, sv2["head"], sv["head"])}
+                so3 = _sel_server_state(so2, so, keep_s, act)
+                ys = (list(g_units[:CU]), jnp.where(act, loss, 0.0))
             if ef:
-                ys = ys + (jnp.where(act, res_new, res_j),)
+                with jax.named_scope("asfl.wire"):
+                    ys = ys + (jnp.where(act, res_new, res_j),)
             return (sv3, so3), ys
 
         def rsu_round_seq(edge_tree, members, mask, cut_slots, idx_slots,
@@ -753,17 +764,21 @@ class SuperStepPrograms:
                  st_num_in, st_den_in) = extra[i:]
             sv = {"units": list(edge_tree["units"]),
                   "head": edge_tree["head"]}
-            so = opt.init(sv)
+            with jax.named_scope("asfl.server_update"):
+                so = opt.init(sv)
             # ragged layout: replicas exist only for the CU units a cut can
             # reach — the per-slot memory and deferred-update math shrink
             # to the owned prefix
-            cu = [jax.tree.map(
-                lambda a: jnp.broadcast_to(a, (C,) + a.shape), u)
-                for u in edge_tree["units"][:CU]]
-            co = jax.vmap(opt.init)(cu)
-            w_slots = lengths_f[members] * mask          # (C,)
+            with jax.named_scope("asfl.client_update"):
+                cu = [jax.tree.map(
+                    lambda a: jnp.broadcast_to(a, (C,) + a.shape), u)
+                    for u in edge_tree["units"][:CU]]
+                co = jax.vmap(opt.init)(cu)
+            with jax.named_scope("asfl.merge"):
+                w_slots = lengths_f[members] * mask      # (C,)
             if not fz:
-                keep_cu = [mask & (cut_slots > u) for u in range(CU)]
+                with jax.named_scope("asfl.client_update"):
+                    keep_cu = [mask & (cut_slots > u) for u in range(CU)]
 
             def step_body(carry, x_s):
                 if fz:
@@ -776,127 +791,138 @@ class SuperStepPrograms:
                 else:
                     sv, so, cu, co = carry
                     xs = (cu, members, cut_slots, act_s, idx_s)
-                (sv, so), ys = lax.scan(
-                    seq_slot_body, (sv, so), xs,
-                    unroll=2 if C >= 64 else 1)
+                # the scan's own work (each slot's replica sliced in, its
+                # client gradient stacked out) is the deferred client update
+                with jax.named_scope("asfl.client_update"):
+                    (sv, so), ys = lax.scan(
+                        seq_slot_body, (sv, so), xs,
+                        unroll=2 if C >= 64 else 1)
                 if ef:
                     g_cu, losses, res = ys
                 else:
                     g_cu, losses = ys
-                upd_c, co2 = jax.vmap(opt.update)(g_cu, co, cu)
-                cu2 = optim.apply_updates(cu, upd_c)
-                # a dropout's replica stops updating at its drop step (per-
-                # step keep); the zero-fault path keeps the hoisted masks
-                keep_s = ([act_s & (cut_slots > u) for u in range(CU)]
-                          if fz else keep_cu)
-                cu = [_select(keep_s[u], cu2[u], cu[u])
-                      for u in range(CU)]
-                co = _sel_list_state(co2, co, keep_s, jnp.asarray(act_s))
-                out = (sv, so, cu, co, res) if ef else (sv, so, cu, co)
-                return out, (jnp.sum(losses),
-                             jnp.sum(act_s.astype(jnp.float32)))
+                with jax.named_scope("asfl.client_update"):
+                    upd_c, co2 = jax.vmap(opt.update)(g_cu, co, cu)
+                    cu2 = optim.apply_updates(cu, upd_c)
+                    # a dropout's replica stops updating at its drop step
+                    # (per-step keep); the zero-fault path keeps the
+                    # hoisted masks
+                    keep_s = ([act_s & (cut_slots > u) for u in range(CU)]
+                              if fz else keep_cu)
+                    cu = [_select(keep_s[u], cu2[u], cu[u])
+                          for u in range(CU)]
+                    co = _sel_list_state(co2, co, keep_s,
+                                         jnp.asarray(act_s))
+                    out = (sv, so, cu, co, res) if ef else (sv, so, cu, co)
+                    return out, (jnp.sum(losses),
+                                 jnp.sum(act_s.astype(jnp.float32)))
 
             init = (sv, so, cu, co, res_slots) if ef else (sv, so, cu, co)
             xs_steps = (idx_slots, act_steps) if fz else idx_slots
             (sv, so, cu, co, *res_t), (ls, cs) = lax.scan(
                 step_body, init, xs_steps,
                 unroll=min(steps, 2))
-            if fz:
-                # survivor weights (DESIGN.md §13): a dropped / lost /
-                # straggling slot's client update folds into the FedAvg as
-                # an exact +0 and the denominator renormalizes over the
-                # survivors.  Server-side contributions stand for every
-                # in-round-active slot — those gradients already landed on
-                # the RSU's own copy
-                w_merge = lengths_f[members] * surv_slots
-                w_bank = lengths_f[members] * strag_slots
-            else:
-                w_merge = w_slots
-            w_total = jnp.sum(w_merge)
-            den = jnp.maximum(w_total, 1.0)
-            merged, st_num_out, st_den_out = [], [], []
-            for u in range(U):
-                if u >= CU:
-                    # no replica exists past the bucket: every slot's
-                    # weight lands on the server copy, so the unit-wise
-                    # FedAvg collapses to (w_total * sv) / den — the value
-                    # the dense path computes through its all-zero client
-                    # weights
-                    merged.append(jax.tree.map(
-                        lambda s, ref: jnp.where(
-                            w_total > 0.0,
-                            ((w_total * s.astype(jnp.float32))
-                             / den).astype(ref.dtype), ref),
-                        sv["units"][u], edge_tree["units"][u]))
-                    continue
-                w_u = w_slots * (cut_slots > u)
+            with jax.named_scope("asfl.merge"):
                 if fz:
-                    # survivor-weighted numerator + last round's staleness
-                    # bank at the discount; den_u can sit in (0, 1) when
-                    # only discounted bank weight remains, so the guard is
-                    # a where, not a max
-                    num = aggregation.survivor_weighted_sum(
-                        cu[u], w_u, surv_slots)
-                    swu = w_total - jnp.sum(w_merge * (cut_slots > u))
-                    den_u = w_total + disc * st_den_in[u]
-                    den_safe = jnp.where(den_u > 0.0, den_u, 1.0)
+                    # survivor weights (DESIGN.md §13): a dropped / lost /
+                    # straggling slot's client update folds into the FedAvg as
+                    # an exact +0 and the denominator renormalizes over the
+                    # survivors.  Server-side contributions stand for every
+                    # in-round-active slot — those gradients already landed on
+                    # the RSU's own copy
+                    w_merge = lengths_f[members] * surv_slots
+                    w_bank = lengths_f[members] * strag_slots
+                else:
+                    w_merge = w_slots
+                w_total = jnp.sum(w_merge)
+                den = jnp.maximum(w_total, 1.0)
+                merged, st_num_out, st_den_out = [], [], []
+                for u in range(U):
+                    if u >= CU:
+                        # no replica exists past the bucket: every slot's
+                        # weight lands on the server copy, so the unit-wise
+                        # FedAvg collapses to (w_total * sv) / den — the value
+                        # the dense path computes through its all-zero client
+                        # weights
+                        merged.append(jax.tree.map(
+                            lambda s, ref: jnp.where(
+                                w_total > 0.0,
+                                ((w_total * s.astype(jnp.float32))
+                                 / den).astype(ref.dtype), ref),
+                            sv["units"][u], edge_tree["units"][u]))
+                        continue
+                    w_u = w_slots * (cut_slots > u)
+                    if fz:
+                        # survivor-weighted numerator + last round's staleness
+                        # bank at the discount; den_u can sit in (0, 1) when
+                        # only discounted bank weight remains, so the guard is
+                        # a where, not a max
+                        num = aggregation.survivor_weighted_sum(
+                            cu[u], w_u, surv_slots)
+                        swu = w_total - jnp.sum(w_merge * (cut_slots > u))
+                        den_u = w_total + disc * st_den_in[u]
+                        den_safe = jnp.where(den_u > 0.0, den_u, 1.0)
+                        num = jax.tree.map(
+                            lambda nm, s, st: (nm + swu * s.astype(jnp.float32)
+                                               + disc * st),
+                            num, sv["units"][u], st_num_in[u])
+                        merged.append(jax.tree.map(
+                            lambda nm, ref: jnp.where(
+                                den_u > 0.0,
+                                (nm / den_safe).astype(ref.dtype), ref),
+                            num, edge_tree["units"][u]))
+                        # this round's bank: straggler replicas fold with the
+                        # same exact-+0 masking and merge NEXT round
+                        st_num_out.append(aggregation.survivor_weighted_sum(
+                            cu[u], w_u, strag_slots))
+                        st_den_out.append(jnp.sum(w_bank * (cut_slots > u)))
+                        continue
+                    swu = w_total - jnp.sum(w_u)
+                    num = aggregation.stacked_weighted_sum(cu[u], w_u)
                     num = jax.tree.map(
-                        lambda nm, s, st: (nm + swu * s.astype(jnp.float32)
-                                           + disc * st),
-                        num, sv["units"][u], st_num_in[u])
+                        lambda nm, s: nm + swu * s.astype(jnp.float32),
+                        num, sv["units"][u])
                     merged.append(jax.tree.map(
                         lambda nm, ref: jnp.where(
-                            den_u > 0.0,
-                            (nm / den_safe).astype(ref.dtype), ref),
+                            w_total > 0.0, (nm / den).astype(ref.dtype), ref),
                         num, edge_tree["units"][u]))
-                    # this round's bank: straggler replicas fold with the
-                    # same exact-+0 masking and merge NEXT round
-                    st_num_out.append(aggregation.survivor_weighted_sum(
-                        cu[u], w_u, strag_slots))
-                    st_den_out.append(jnp.sum(w_bank * (cut_slots > u)))
-                    continue
-                swu = w_total - jnp.sum(w_u)
-                num = aggregation.stacked_weighted_sum(cu[u], w_u)
-                num = jax.tree.map(
-                    lambda nm, s: nm + swu * s.astype(jnp.float32),
-                    num, sv["units"][u])
-                merged.append(jax.tree.map(
-                    lambda nm, ref: jnp.where(
-                        w_total > 0.0, (nm / den).astype(ref.dtype), ref),
-                    num, edge_tree["units"][u]))
-            out = {"units": merged, "head": sv["head"]}
-            rets = [out, jnp.sum(ls), jnp.sum(cs), w_total]
-            if ef:
-                rets.append(res_t[0])
-            if fz:
-                rets.append(st_num_out)
-                rets.append(jnp.stack(st_den_out))
-            return tuple(rets)
+                out = {"units": merged, "head": sv["head"]}
+                rets = [out, jnp.sum(ls), jnp.sum(cs), w_total]
+                if ef:
+                    rets.append(res_t[0])
+                if fz:
+                    rets.append(st_num_out)
+                    rets.append(jnp.stack(st_den_out))
+                return tuple(rets)
 
         # ---- parallel schedule (arXiv:2405.18707: the RSUs execute the
         # cohorts' server-side passes in parallel and take one weighted
         # mean-gradient step per local step) ------------------------------
         def par_slot_grad(cu_j, cut_j, m_j, idx_j, sv_j, res_j=None):
-            x = images[m_j][idx_j]
-            y = labels[m_j][idx_j]
+            with jax.named_scope("asfl.batch"):
+                x = images[m_j][idx_j]
+                y = labels[m_j][idx_j]
             # the effective plane: the slot's prefix replica where owned,
             # the serving RSU's plane elsewhere.  Dense layout: O = 0 and
             # W = P, so this is the old full-plane select verbatim
-            own = jnp.where(unit_ids_w < cut_j, cu_j, sv_j[O:O + W])
-            if O > 0 or O + W < P:
-                plane = jnp.concatenate([sv_j[:O], own, sv_j[O + W:]])
-            else:
-                plane = own
-            eff = unravel(plane)
-            if wire == "none":
-                (loss, _), (g_units, g_head) = jax.value_and_grad(
-                    loss_fn, argnums=(0, 1), has_aux=True)(
-                        eff["units"], eff["head"], x, y)
-            else:
-                (loss, res_new), (g_units, g_head) = jax.value_and_grad(
-                    wire_loss, argnums=(0, 1), has_aux=True)(
-                        eff["units"], eff["head"], x, y, cut_j, res_j)
-            g = ravel_pytree({"units": list(g_units), "head": g_head})[0]
+            with jax.named_scope("asfl.server_update"):
+                own = jnp.where(unit_ids_w < cut_j, cu_j, sv_j[O:O + W])
+                if O > 0 or O + W < P:
+                    plane = jnp.concatenate([sv_j[:O], own, sv_j[O + W:]])
+                else:
+                    plane = own
+                eff = unravel(plane)
+            with jax.named_scope("asfl.grad"):
+                if wire == "none":
+                    (loss, _), (g_units, g_head) = jax.value_and_grad(
+                        loss_fn, argnums=(0, 1), has_aux=True)(
+                            eff["units"], eff["head"], x, y)
+                else:
+                    (loss, res_new), (g_units, g_head) = jax.value_and_grad(
+                        wire_loss, argnums=(0, 1), has_aux=True)(
+                            eff["units"], eff["head"], x, y, cut_j, res_j)
+                g = ravel_pytree({"units": list(g_units),
+                                  "head": g_head})[0]
             if ef:
                 return g, loss, res_new
             return g, loss
@@ -925,10 +951,11 @@ class SuperStepPrograms:
             if fz:
                 (act_slots_l, surv_sl, strag_sl,
                  st_num_in, st_den_in) = extra[i:]
-            slot_mask_l = slot_seg_l < R_srv             # (S_loc,)
-            seg_gather = jnp.minimum(slot_seg_l, R_srv - 1)
-            cut_slots_l = cuts[members_l]
-            w_slots_l = lengths_f[members_l] * slot_mask_l
+            with jax.named_scope("asfl.control"):
+                slot_mask_l = slot_seg_l < R_srv         # (S_loc,)
+                seg_gather = jnp.minimum(slot_seg_l, R_srv - 1)
+                cut_slots_l = cuts[members_l]
+                w_slots_l = lengths_f[members_l] * slot_mask_l
 
             def regroup(vals):
                 """Order-restoring gather over the vehicle sub-axis
@@ -942,7 +969,8 @@ class SuperStepPrograms:
                 v = jnp.moveaxis(v, 0, 1)               # (R_loc, dv, C_loc)
                 return v.reshape((R_loc * C,) + vals.shape[1:])
 
-            seg_full = regroup(slot_seg_l) if dense2d else slot_seg_l
+            with jax.named_scope("asfl.control"):
+                seg_full = regroup(slot_seg_l) if dense2d else slot_seg_l
 
             def seg_sum(vals):
                 if dense2d:
@@ -951,18 +979,22 @@ class SuperStepPrograms:
                                 vals.dtype).at[seg_full].add(vals)[:R_srv]
                 return lax.psum(out, ALL_AXES) if psum_out else out
 
-            w_seg = seg_sum(w_slots_l)                   # (R_srv,)
-            den = jnp.maximum(w_seg, 1.0)
-            any_active = w_seg > 0.0
-            gw = w_slots_l / den[seg_gather]             # (S_loc,)
-            # (S_loc, P) / (S_loc, W): positions each slot's replica owns
-            keep_full = slot_mask_l[:, None] \
-                & (unit_ids[None, :] < cut_slots_l[:, None])
-            keep_w = keep_full[:, O:O + W]
+            with jax.named_scope("asfl.control"):
+                w_seg = seg_sum(w_slots_l)               # (R_srv,)
+                den = jnp.maximum(w_seg, 1.0)
+                any_active = w_seg > 0.0
+                gw = w_slots_l / den[seg_gather]         # (S_loc,)
+                # (S_loc, P) / (S_loc, W): positions each slot's replica
+                # owns
+                keep_full = slot_mask_l[:, None] \
+                    & (unit_ids[None, :] < cut_slots_l[:, None])
+                keep_w = keep_full[:, O:O + W]
             sv0 = edge_stack_in                          # (R_srv, P)
-            cu = sv0[:, O:O + W][seg_gather]             # (S_loc, W)
-            co = jax.vmap(opt.init)(cu)
-            so = jax.vmap(opt.init)(sv0)
+            with jax.named_scope("asfl.client_update"):
+                cu = sv0[:, O:O + W][seg_gather]         # (S_loc, W)
+                co = jax.vmap(opt.init)(cu)
+            with jax.named_scope("asfl.server_update"):
+                so = jax.vmap(opt.init)(sv0)
 
             def paged_sweep(sv_stack, cu, idx_s, amask, gw_s, res):
                 """One local step's fwd/bwd in fixed slot windows
@@ -984,7 +1016,8 @@ class SuperStepPrograms:
 
                 def page_fn(accs, xp):
                     g_acc, l_acc = accs
-                    sv_g = sv_stack[jnp.minimum(xp["seg"], R_srv - 1)]
+                    with jax.named_scope("asfl.server_update"):
+                        sv_g = sv_stack[jnp.minimum(xp["seg"], R_srv - 1)]
                     if ef:
                         g, losses, res_n = jax.vmap(
                             par_slot_grad, in_axes=(0, 0, 0, 0, 0, 0))(
@@ -995,27 +1028,33 @@ class SuperStepPrograms:
                             par_slot_grad, in_axes=(0, 0, 0, 0, 0))(
                                 xp["cu"], xp["cut"], xp["m"], xp["idx"],
                                 sv_g)
-                    keep_p = xp["mask"][:, None] \
-                        & (unit_ids[None, :] < xp["cut"][:, None])
-                    contrib = jnp.where(keep_p, 0.0, g) * xp["gw"][:, None]
-                    g_acc = g_acc.at[xp["seg"]].add(contrib)
-                    l_acc = l_acc.at[xp["seg"]].add(
-                        jnp.where(xp["amask"], losses, 0.0))
-                    ys = (g[:, O:O + W], res_n) if ef else g[:, O:O + W]
+                    with jax.named_scope("asfl.server_update"):
+                        keep_p = xp["mask"][:, None] \
+                            & (unit_ids[None, :] < xp["cut"][:, None])
+                        contrib = jnp.where(keep_p, 0.0, g) \
+                            * xp["gw"][:, None]
+                        g_acc = g_acc.at[xp["seg"]].add(contrib)
+                        l_acc = l_acc.at[xp["seg"]].add(
+                            jnp.where(xp["amask"], losses, 0.0))
+                    with jax.named_scope("asfl.client_update"):
+                        ys = (g[:, O:O + W], res_n) if ef \
+                            else g[:, O:O + W]
                     return (g_acc, l_acc), ys
 
-                accs0 = (jnp.zeros((R_srv + 1, P), jnp.float32),
-                         jnp.zeros((R_srv + 1,), jnp.float32))
+                with jax.named_scope("asfl.server_update"):
+                    accs0 = (jnp.zeros((R_srv + 1, P), jnp.float32),
+                             jnp.zeros((R_srv + 1,), jnp.float32))
                 (g_acc, l_acc), ys = lax.scan(page_fn, accs0, xs)
-                if ef:
-                    g_w = ys[0].reshape(S_loc, W)
-                    res_new = ys[1].reshape(S_loc, res_size)
-                else:
-                    g_w, res_new = ys.reshape(S_loc, W), None
-                g_srv, ls_seg = g_acc[:R_srv], l_acc[:R_srv]
-                if psum_out:
-                    g_srv = lax.psum(g_srv, ALL_AXES)
-                    ls_seg = lax.psum(ls_seg, ALL_AXES)
+                with jax.named_scope("asfl.server_update"):
+                    if ef:
+                        g_w = ys[0].reshape(S_loc, W)
+                        res_new = ys[1].reshape(S_loc, res_size)
+                    else:
+                        g_w, res_new = ys.reshape(S_loc, W), None
+                    g_srv, ls_seg = g_acc[:R_srv], l_acc[:R_srv]
+                    if psum_out:
+                        g_srv = lax.psum(g_srv, ALL_AXES)
+                        ls_seg = lax.psum(ls_seg, ALL_AXES)
                 return g_w, g_srv, ls_seg, res_new
 
             def step_body(carry, x_s):
@@ -1033,48 +1072,58 @@ class SuperStepPrograms:
                     # contributing weight (and gradient) after its drop
                     # step, so the server's |D_n|-weighted mean-gradient
                     # renormalizes per step over the still-active slots
-                    amask = slot_mask_l & act_s
-                    w_act = w_slots_l * act_s
-                    w_seg_s = seg_sum(w_act)
-                    den_s = jnp.maximum(w_seg_s, 1.0)
-                    gw_s = w_act / den_s[seg_gather]
-                    any_s = w_seg_s > 0.0
+                    with jax.named_scope("asfl.control"):
+                        amask = slot_mask_l & act_s
+                        w_act = w_slots_l * act_s
+                        w_seg_s = seg_sum(w_act)
+                        den_s = jnp.maximum(w_seg_s, 1.0)
+                        gw_s = w_act / den_s[seg_gather]
+                        any_s = w_seg_s > 0.0
                 else:
                     amask, gw_s, any_s = slot_mask_l, gw, any_active
                 if paged:
                     g_w, g_srv, ls_seg, res_new = paged_sweep(
                         sv_stack, cu, idx_s, amask, gw_s, res)
                 else:
+                    with jax.named_scope("asfl.server_update"):
+                        sv_g = sv_stack[seg_gather]
                     if ef:
                         g, losses, res_new = jax.vmap(
                             par_slot_grad, in_axes=(0, 0, 0, 0, 0, 0))(
                                 cu, cut_slots_l, members_l, idx_s,
-                                sv_stack[seg_gather], res)
+                                sv_g, res)
                     else:
                         g, losses = jax.vmap(
                             par_slot_grad, in_axes=(0, 0, 0, 0, 0))(
                                 cu, cut_slots_l, members_l, idx_s,
-                                sv_stack[seg_gather])
+                                sv_g)
                         res_new = None
                     # RSUs: one |D_n|-weighted mean-gradient step each
                     # over their cohorts' server-side gradient shares
-                    contrib = jnp.where(keep_full, 0.0, g) * gw_s[:, None]
-                    g_srv = seg_sum(contrib)             # (R_srv, P)
-                    ls_seg = seg_sum(jnp.where(amask, losses, 0.0))
-                    g_w = g[:, O:O + W]
+                    with jax.named_scope("asfl.server_update"):
+                        contrib = jnp.where(keep_full, 0.0, g) \
+                            * gw_s[:, None]
+                        g_srv = seg_sum(contrib)         # (R_srv, P)
+                        ls_seg = seg_sum(jnp.where(amask, losses, 0.0))
+                    with jax.named_scope("asfl.client_update"):
+                        g_w = g[:, O:O + W]
                 if ef:
-                    res = jnp.where(amask[:, None], res_new, res)
-                upd_s, so2 = jax.vmap(opt.update)(g_srv, so, sv_stack)
-                sv2 = optim.apply_updates(sv_stack, upd_s)
-                sv_stack = jnp.where(any_s[:, None], sv2, sv_stack)
-                so = _sel_flat_state(any_s[:, None], any_s,
-                                     so2, so, sv_stack.shape)
+                    with jax.named_scope("asfl.wire"):
+                        res = jnp.where(amask[:, None], res_new, res)
+                with jax.named_scope("asfl.server_update"):
+                    upd_s, so2 = jax.vmap(opt.update)(g_srv, so, sv_stack)
+                    sv2 = optim.apply_updates(sv_stack, upd_s)
+                    sv_stack = jnp.where(any_s[:, None], sv2, sv_stack)
+                    so = _sel_flat_state(any_s[:, None], any_s,
+                                         so2, so, sv_stack.shape)
                 # vehicles: per-replica prefix updates over the slot axis
-                upd_c, co2 = jax.vmap(opt.update)(g_w, co, cu)
-                keep_w_s = keep_w & act_s[:, None] if fz else keep_w
-                cu = jnp.where(keep_w_s, optim.apply_updates(cu, upd_c), cu)
-                co = _sel_flat_state(keep_w_s, amask, co2, co,
-                                     cu.shape)
+                with jax.named_scope("asfl.client_update"):
+                    upd_c, co2 = jax.vmap(opt.update)(g_w, co, cu)
+                    keep_w_s = keep_w & act_s[:, None] if fz else keep_w
+                    cu = jnp.where(keep_w_s,
+                                   optim.apply_updates(cu, upd_c), cu)
+                    co = _sel_flat_state(keep_w_s, amask, co2, co,
+                                         cu.shape)
                 out = (sv_stack, so, cu, co, res) if ef \
                     else (sv_stack, so, cu, co)
                 return out, ls_seg
@@ -1085,202 +1134,209 @@ class SuperStepPrograms:
             (sv_stack, so, cu, co, *res_t), ls_steps = lax.scan(
                 step_body, init, xs_steps,
                 unroll=min(steps, 4))
-            ls_rows = jnp.sum(ls_steps, axis=0)          # (R_srv,)
-            if fz:
-                # survivor-weighted unit-wise FedAvg (DESIGN.md §13): the
-                # merge weight is the SURVIVING slot weight — dropped /
-                # lost / straggling slots fold in as exact +0 — plus last
-                # round's staleness bank at the discount.  The per-position
-                # denominator can sit in (0, 1) when only discounted bank
-                # weight remains, so the guards are wheres, not maxes
-                w_surv = w_slots_l * surv_sl.astype(jnp.float32)
-                w_seg_m = seg_sum(w_surv)                # (R_srv,)
-                wk = w_surv[:, None] * keep_w            # (S_loc, W)
-                num = seg_sum(wk * cu)                   # (R_srv, W)
-                w_srv = w_seg_m[:, None] - seg_sum(wk)
-                svw = sv_stack[:, O:O + W]
-                st_den_pos = st_den_in[:, unit_ids_w]    # (R_srv, W)
-                den_pos = w_seg_m[:, None] + disc * st_den_pos
-                den_pos_safe = jnp.where(den_pos > 0.0, den_pos, 1.0)
-                merged_w = jnp.where(
-                    den_pos > 0.0,
-                    (num + w_srv * svw + disc * st_num_in) / den_pos_safe,
-                    edge_stack_in[:, O:O + W])
-                row_act = w_seg_m > 0.0
-                den_row = jnp.maximum(w_seg_m, 1.0)
+            with jax.named_scope("asfl.merge"):
+                ls_rows = jnp.sum(ls_steps, axis=0)          # (R_srv,)
+                if fz:
+                    # survivor-weighted unit-wise FedAvg (DESIGN.md §13): the
+                    # merge weight is the SURVIVING slot weight — dropped /
+                    # lost / straggling slots fold in as exact +0 — plus last
+                    # round's staleness bank at the discount.  The per-position
+                    # denominator can sit in (0, 1) when only discounted bank
+                    # weight remains, so the guards are wheres, not maxes
+                    w_surv = w_slots_l * surv_sl.astype(jnp.float32)
+                    w_seg_m = seg_sum(w_surv)                # (R_srv,)
+                    wk = w_surv[:, None] * keep_w            # (S_loc, W)
+                    num = seg_sum(wk * cu)                   # (R_srv, W)
+                    w_srv = w_seg_m[:, None] - seg_sum(wk)
+                    svw = sv_stack[:, O:O + W]
+                    st_den_pos = st_den_in[:, unit_ids_w]    # (R_srv, W)
+                    den_pos = w_seg_m[:, None] + disc * st_den_pos
+                    den_pos_safe = jnp.where(den_pos > 0.0, den_pos, 1.0)
+                    merged_w = jnp.where(
+                        den_pos > 0.0,
+                        (num + w_srv * svw + disc * st_num_in) / den_pos_safe,
+                        edge_stack_in[:, O:O + W])
+                    row_act = w_seg_m > 0.0
+                    den_row = jnp.maximum(w_seg_m, 1.0)
+                    if O > 0 or O + W < P:
+                        edge_new = jnp.concatenate(
+                            [jnp.where(row_act[:, None],
+                                       (w_seg_m[:, None] * sv_stack[:, :O])
+                                       / den_row[:, None],
+                                       edge_stack_in[:, :O]),
+                             merged_w,
+                             jnp.where(row_act[:, None],
+                                       (w_seg_m[:, None] * sv_stack[:, O + W:])
+                                       / den_row[:, None],
+                                       edge_stack_in[:, O + W:])],
+                            axis=1)
+                    else:
+                        edge_new = merged_w
+                    # this round's bank: straggler replicas scattered into the
+                    # same per-RSU segment rows, merged NEXT round
+                    w_st = w_slots_l * strag_sl.astype(jnp.float32)
+                    st_num_out = seg_sum((w_st[:, None] * keep_w) * cu)
+                    unit_own = (cut_slots_l[:, None]
+                                > jnp.arange(CU, dtype=jnp.int32)[None, :])
+                    st_den_out = seg_sum(w_st[:, None] * unit_own)
+                    rets = [edge_new, ls_rows, w_seg_m, slot_mask_l]
+                    if ef:
+                        rets.append(res_t[0])
+                    rets += [st_num_out, st_den_out]
+                    return tuple(rets)
+                # unit-wise FedAvg: segment-sums over the owned window, the
+                # untouched remainder of the plane merges as (w_seg * sv) / den
+                # (its client weight is identically zero)
+                wk = w_slots_l[:, None] * keep_w             # (S_loc, W)
+                num = seg_sum(wk * cu)                       # (R_srv, W)
+                w_srv = w_seg[:, None] - seg_sum(wk)
+                merged_w = (num + w_srv * sv_stack[:, O:O + W]) / den[:, None]
                 if O > 0 or O + W < P:
-                    edge_new = jnp.concatenate(
-                        [jnp.where(row_act[:, None],
-                                   (w_seg_m[:, None] * sv_stack[:, :O])
-                                   / den_row[:, None],
-                                   edge_stack_in[:, :O]),
+                    merged = jnp.concatenate(
+                        [(w_seg[:, None] * sv_stack[:, :O]) / den[:, None],
                          merged_w,
-                         jnp.where(row_act[:, None],
-                                   (w_seg_m[:, None] * sv_stack[:, O + W:])
-                                   / den_row[:, None],
-                                   edge_stack_in[:, O + W:])],
+                         (w_seg[:, None] * sv_stack[:, O + W:])
+                         / den[:, None]],
                         axis=1)
                 else:
-                    edge_new = merged_w
-                # this round's bank: straggler replicas scattered into the
-                # same per-RSU segment rows, merged NEXT round
-                w_st = w_slots_l * strag_sl.astype(jnp.float32)
-                st_num_out = seg_sum((w_st[:, None] * keep_w) * cu)
-                unit_own = (cut_slots_l[:, None]
-                            > jnp.arange(CU, dtype=jnp.int32)[None, :])
-                st_den_out = seg_sum(w_st[:, None] * unit_own)
-                rets = [edge_new, ls_rows, w_seg_m, slot_mask_l]
+                    merged = merged_w
+                edge_new = jnp.where(any_active[:, None], merged,
+                                     edge_stack_in)
                 if ef:
-                    rets.append(res_t[0])
-                rets += [st_num_out, st_den_out]
-                return tuple(rets)
-            # unit-wise FedAvg: segment-sums over the owned window, the
-            # untouched remainder of the plane merges as (w_seg * sv) / den
-            # (its client weight is identically zero)
-            wk = w_slots_l[:, None] * keep_w             # (S_loc, W)
-            num = seg_sum(wk * cu)                       # (R_srv, W)
-            w_srv = w_seg[:, None] - seg_sum(wk)
-            merged_w = (num + w_srv * sv_stack[:, O:O + W]) / den[:, None]
-            if O > 0 or O + W < P:
-                merged = jnp.concatenate(
-                    [(w_seg[:, None] * sv_stack[:, :O]) / den[:, None],
-                     merged_w,
-                     (w_seg[:, None] * sv_stack[:, O + W:]) / den[:, None]],
-                    axis=1)
-            else:
-                merged = merged_w
-            edge_new = jnp.where(any_active[:, None], merged,
-                                 edge_stack_in)
-            if ef:
-                return edge_new, ls_rows, w_seg, slot_mask_l, res_t[0]
-            return edge_new, ls_rows, w_seg, slot_mask_l
+                    return edge_new, ls_rows, w_seg, slot_mask_l, res_t[0]
+                return edge_new, ls_rows, w_seg, slot_mask_l
 
         def round_body(carry, x):
-            rnd = x["rnd"]
-            if sig.staged:
-                serving = x["serving"]
-                rates = x["rates"]
-                residence = x["residence"]
-            else:
-                t = rnd.astype(jnp.float32) * interval
-                fkey = jax.random.fold_in(fading_key, rnd)
-                st = sc.traced_fleet_state(t, fkey)
-                serving, rates, residence = (st.serving_rsu, st.rates_bps,
-                                             st.residence_s)
-            if cz:
-                # presence churn (DESIGN.md §14): each vehicle flips its
-                # presence bit with P[churn_rate], round-keyed so a K-fused
-                # window samples identically to K single rounds.  A vehicle
-                # not admitted this round becomes indistinguishable from
-                # one outside coverage before cut selection.  Synchronous
-                # schedules admit a fresh arrival only NEXT round (it still
-                # has to register and download the cohort model after the
-                # round has formed); the streaming schedule admits it
-                # immediately — its shard is already staged on device by
-                # the double-buffered pipeline, and the buffered merge
-                # never waits on cohort formation
-                if stc.churn_source == "mobility":
-                    # mobility-coupled stream (DESIGN.md §15): departures
-                    # ARE the coverage state — a vehicle whose serving
-                    # cell is -1 has left the stream, one re-entering
-                    # coverage re-registers.  Same admission contract as
-                    # the sampled chain: synchronous schedules admit the
-                    # re-arrival next round, streaming immediately
-                    present2 = serving >= 0
+            with jax.named_scope("asfl.control"):
+                rnd = x["rnd"]
+                if sig.staged:
+                    serving = x["serving"]
+                    rates = x["rates"]
+                    residence = x["residence"]
                 else:
-                    toggle = streaming.sample_toggles_traced(stc, rnd, n)
-                    present2 = carry["present"] ^ toggle
-                arrived = present2 & ~carry["present"]
-                admit = present2 if sz else (present2 & ~arrived)
-                serving, rates, residence = streaming.gate_presence(
-                    serving, rates, residence, admit)
-            cuts = pick_cuts(serving, rates, residence)
-            if fz:
-                drop, dfrac, lost, rsu_down = faults.sample_faults_traced(
-                    fc, rnd, n, R)
-                rsu_down = faults.ensure_rsu_up(rsu_down)
-                # whole-RSU outage: the cohort's cuts drop to SKIP before
-                # slot grouping — the cell trains nothing and accrues no
-                # samples this round, so the cloud merge reweights around
-                # it by construction
-                down_v = rsu_down[jnp.clip(serving, 0, R - 1)] \
-                    & (serving >= 0)
-                cuts = jnp.where(down_v, 0, cuts).astype(jnp.int32)
-            order, seg_v, counts = slot_sort(serving, cuts)
-            idx_all = fleet_batch_indices_traced(
-                jax.random.fold_in(base_key, rnd), lengths_dev, steps, batch)
-            sched = cuts > 0
-            if fz:
-                # failure precedence: a mid-round dropout has nothing left
-                # to upload; an upload loss discards what a straggler
-                # would have banked
-                drop = drop & sched
-                lost = lost & sched & ~drop
-                if fc.straggler_factor > 0.0:
-                    # deadline stragglers are derived, not sampled: the
-                    # analytic round latency at the CHOSEN cut against the
-                    # scaled residence budget
-                    lat_m = adaptive.latency_matrix_traced(
-                        self.profile, jnp.maximum(rates, 1.0), flops,
-                        cfg.server_flops, nb, batch, ep, range(1, U))
-                    lat = lat_m[jnp.arange(n), jnp.clip(cuts - 1, 0, U - 2)]
-                    strag = sched & (lat > fc.straggler_factor * residence)
-                else:
-                    strag = jnp.zeros_like(sched)
-                strag = strag & ~drop & ~lost
-                rescue = faults.rescue_mask(sched, drop | lost | strag)
-                drop = drop & ~rescue
-                lost = lost & ~rescue
-                strag = strag & ~rescue
-                surv = sched & ~drop & ~lost & ~strag
-                dstep = faults.drop_steps(drop, dfrac, steps)
-                # (steps, n) per-step activity: a dropout runs only its
-                # first dstep local batches; everyone else runs them all
-                act_v = (jnp.arange(steps, dtype=jnp.int32)[:, None]
-                         < dstep[None, :]) & sched[None, :]
-                # banked weight merging THIS round (telemetry)
-                stale_w = jnp.sum(carry["stale_den"])
-                if fm is not None and not ragged_par:
-                    # the bank is per-RSU state, sharded over the RSU axis
-                    # and replicated across the vehicle sub-axis — psum
-                    # over the RSU axis only (both would multiply by dv)
-                    stale_w = lax.psum(stale_w, RSU_AXIS)
-            if ef:
-                # residuals follow the vehicle (the plane is fleet-indexed
-                # and replicated): zero where this round's cut differs from
-                # the one the buffer was accumulated at, then gather each
-                # shard's slot view
-                stale = sched & (cuts != carry["wire_cut"])
-                res_base = jnp.where(stale[:, None], 0.0,
-                                     carry["wire_res"])
-            if self.schedule == "sequential":
-                members, mask = slot_table_seq(order, counts)
-                if fm is not None:
-                    # the slot table is fleet-wide and replicated; each
-                    # RSU-axis shard trains its contiguous block of RSU
-                    # rows.  The sequential schedule is a per-RSU slot
-                    # CHAIN (slot i+1's server pass consumes slot i's
-                    # updated state), so the vehicle sub-axis has nothing
-                    # to split — it replicates the chain (DESIGN.md §15)
-                    members_l = fleet_sharding.local_slice(
-                        members, R_loc, axes=(RSU_AXIS,))
-                    mask_l = fleet_sharding.local_slice(
-                        mask, R_loc, axes=(RSU_AXIS,))
-                else:
-                    members_l, mask_l = members, mask
-                idx_rsu = jnp.moveaxis(idx_all[:, members_l], 1, 0)
-                cut_slots = cuts[members_l]            # (R_loc, C)
-                args = [carry["edge"], members_l, mask_l, cut_slots,
-                        idx_rsu]
-                if ef:
-                    res_slots = res_base[members_l]    # (R_loc, C, res)
-                    args.append(res_slots)
+                    t = rnd.astype(jnp.float32) * interval
+                    fkey = jax.random.fold_in(fading_key, rnd)
+                    st = sc.traced_fleet_state(t, fkey)
+                    serving, rates, residence = (st.serving_rsu, st.rates_bps,
+                                                 st.residence_s)
+                if cz:
+                    # presence churn (DESIGN.md §14): each vehicle flips its
+                    # presence bit with P[churn_rate], round-keyed so a K-fused
+                    # window samples identically to K single rounds.  A vehicle
+                    # not admitted this round becomes indistinguishable from
+                    # one outside coverage before cut selection.  Synchronous
+                    # schedules admit a fresh arrival only NEXT round (it still
+                    # has to register and download the cohort model after the
+                    # round has formed); the streaming schedule admits it
+                    # immediately — its shard is already staged on device by
+                    # the double-buffered pipeline, and the buffered merge
+                    # never waits on cohort formation
+                    if stc.churn_source == "mobility":
+                        # mobility-coupled stream (DESIGN.md §15): departures
+                        # ARE the coverage state — a vehicle whose serving
+                        # cell is -1 has left the stream, one re-entering
+                        # coverage re-registers.  Same admission contract as
+                        # the sampled chain: synchronous schedules admit the
+                        # re-arrival next round, streaming immediately
+                        present2 = serving >= 0
+                    else:
+                        toggle = streaming.sample_toggles_traced(stc, rnd, n)
+                        present2 = carry["present"] ^ toggle
+                    arrived = present2 & ~carry["present"]
+                    admit = present2 if sz else (present2 & ~arrived)
+                    serving, rates, residence = streaming.gate_presence(
+                        serving, rates, residence, admit)
+                cuts = pick_cuts(serving, rates, residence)
                 if fz:
-                    act_rsu = jnp.moveaxis(act_v[:, members_l], 1, 0) \
-                        & mask_l[:, None, :]           # (R_loc, steps, C)
-                    args += [act_rsu, surv[members_l] & mask_l,
-                             strag[members_l] & mask_l,
-                             carry["stale_num"], carry["stale_den"]]
+                    drop, dfrac, lost, rsu_down = faults.sample_faults_traced(
+                        fc, rnd, n, R)
+                    rsu_down = faults.ensure_rsu_up(rsu_down)
+                    # whole-RSU outage: the cohort's cuts drop to SKIP before
+                    # slot grouping — the cell trains nothing and accrues no
+                    # samples this round, so the cloud merge reweights around
+                    # it by construction
+                    down_v = rsu_down[jnp.clip(serving, 0, R - 1)] \
+                        & (serving >= 0)
+                    cuts = jnp.where(down_v, 0, cuts).astype(jnp.int32)
+                order, seg_v, counts = slot_sort(serving, cuts)
+                idx_all = fleet_batch_indices_traced(
+                    jax.random.fold_in(base_key, rnd), lengths_dev, steps,
+                    batch)
+                sched = cuts > 0
+                if fz:
+                    # failure precedence: a mid-round dropout has nothing left
+                    # to upload; an upload loss discards what a straggler
+                    # would have banked
+                    drop = drop & sched
+                    lost = lost & sched & ~drop
+                    if fc.straggler_factor > 0.0:
+                        # deadline stragglers are derived, not sampled: the
+                        # analytic round latency at the CHOSEN cut against the
+                        # scaled residence budget
+                        lat_m = adaptive.latency_matrix_traced(
+                            self.profile, jnp.maximum(rates, 1.0), flops,
+                            cfg.server_flops, nb, batch, ep, range(1, U))
+                        lat = lat_m[jnp.arange(n),
+                                    jnp.clip(cuts - 1, 0, U - 2)]
+                        strag = sched & (lat > fc.straggler_factor * residence)
+                    else:
+                        strag = jnp.zeros_like(sched)
+                    strag = strag & ~drop & ~lost
+                    rescue = faults.rescue_mask(sched, drop | lost | strag)
+                    drop = drop & ~rescue
+                    lost = lost & ~rescue
+                    strag = strag & ~rescue
+                    surv = sched & ~drop & ~lost & ~strag
+                    dstep = faults.drop_steps(drop, dfrac, steps)
+                    # (steps, n) per-step activity: a dropout runs only its
+                    # first dstep local batches; everyone else runs them all
+                    act_v = (jnp.arange(steps, dtype=jnp.int32)[:, None]
+                             < dstep[None, :]) & sched[None, :]
+                    # banked weight merging THIS round (telemetry)
+                    stale_w = jnp.sum(carry["stale_den"])
+                    if fm is not None and not ragged_par:
+                        # the bank is per-RSU state, sharded over the RSU axis
+                        # and replicated across the vehicle sub-axis — psum
+                        # over the RSU axis only (both would multiply by dv)
+                        stale_w = lax.psum(stale_w, RSU_AXIS)
+            with jax.named_scope("asfl.wire"):
+                if ef:
+                    # residuals follow the vehicle (the plane is fleet-indexed
+                    # and replicated): zero where this round's cut differs from
+                    # the one the buffer was accumulated at, then gather each
+                    # shard's slot view
+                    stale = sched & (cuts != carry["wire_cut"])
+                    res_base = jnp.where(stale[:, None], 0.0,
+                                         carry["wire_res"])
+            if self.schedule == "sequential":
+                with jax.named_scope("asfl.control"):
+                    members, mask = slot_table_seq(order, counts)
+                    if fm is not None:
+                        # the slot table is fleet-wide and replicated; each
+                        # RSU-axis shard trains its contiguous block of RSU
+                        # rows.  The sequential schedule is a per-RSU slot
+                        # CHAIN (slot i+1's server pass consumes slot i's
+                        # updated state), so the vehicle sub-axis has nothing
+                        # to split — it replicates the chain (DESIGN.md §15)
+                        members_l = fleet_sharding.local_slice(
+                            members, R_loc, axes=(RSU_AXIS,))
+                        mask_l = fleet_sharding.local_slice(
+                            mask, R_loc, axes=(RSU_AXIS,))
+                    else:
+                        members_l, mask_l = members, mask
+                    idx_rsu = jnp.moveaxis(idx_all[:, members_l], 1, 0)
+                    cut_slots = cuts[members_l]            # (R_loc, C)
+                    args = [carry["edge"], members_l, mask_l, cut_slots,
+                            idx_rsu]
+                    if ef:
+                        res_slots = res_base[members_l]    # (R_loc, C, res)
+                        args.append(res_slots)
+                    if fz:
+                        act_rsu = jnp.moveaxis(act_v[:, members_l], 1, 0) \
+                            & mask_l[:, None, :]           # (R_loc, steps, C)
+                        args += [act_rsu, surv[members_l] & mask_l,
+                                 strag[members_l] & mask_l,
+                                 carry["stale_num"], carry["stale_den"]]
                 outs = jax.vmap(rsu_round_seq)(*args)
                 if fz:
                     st_num2, st_den2 = outs[-2], outs[-1]
@@ -1290,57 +1346,59 @@ class SuperStepPrograms:
                 else:
                     edge, ls, cs, w_tot = outs
                 ef_mask, ef_members = mask_l, members_l
-                cnt = jnp.sum(cs)
-                if fm is not None:
-                    # per-RSU results come home via all_gather so every
-                    # total (loss/count sums, the sample counters, the
-                    # cloud merge) reduces the full (R,) stack in the SAME
-                    # order as the single-device program — gather-then-
-                    # reduce is the order-preserving form of the weighted
-                    # all-reduce, which is what keeps sharded sgd
-                    # bit-for-bit (a psum of per-shard partials would
-                    # reassociate the fp additions)
-                    ls = lax.all_gather(ls, RSU_AXIS, tiled=True)
-                    cnt = jnp.sum(lax.all_gather(cs, RSU_AXIS,
-                                                 tiled=True))
-                    w_tot = lax.all_gather(w_tot, RSU_AXIS, tiled=True)
-                    edge_stack = aggregation.gathered_stack(edge,
-                                                            RSU_AXIS)
-                else:
-                    edge_stack = edge
+                with jax.named_scope("asfl.merge"):
+                    cnt = jnp.sum(cs)
+                    if fm is not None:
+                        # per-RSU results come home via all_gather so every
+                        # total (loss/count sums, the sample counters, the
+                        # cloud merge) reduces the full (R,) stack in the SAME
+                        # order as the single-device program — gather-then-
+                        # reduce is the order-preserving form of the weighted
+                        # all-reduce, which is what keeps sharded sgd
+                        # bit-for-bit (a psum of per-shard partials would
+                        # reassociate the fp additions)
+                        ls = lax.all_gather(ls, RSU_AXIS, tiled=True)
+                        cnt = jnp.sum(lax.all_gather(cs, RSU_AXIS,
+                                                     tiled=True))
+                        w_tot = lax.all_gather(w_tot, RSU_AXIS, tiled=True)
+                        edge_stack = aggregation.gathered_stack(edge,
+                                                                RSU_AXIS)
+                    else:
+                        edge_stack = edge
             else:
-                members, slot_seg = slot_table_flat(order, seg_v, counts)
-                if fm is None:
-                    members_l, slot_seg_l = members, slot_seg
-                elif layout == "dense":
-                    # RSU-aligned tiles: device (i, j)'s slots are its
-                    # R_loc rows x C_loc columns of the padded (R, C)
-                    # grid (with dv == 1 that is exactly the old R_loc-row
-                    # block); localize segment ids and clip the phantom
-                    # segment R onto the local drop row
-                    members_l = fleet_sharding.local_block2d(
-                        members.reshape(R, C), R_loc, C_loc).reshape(-1)
-                    seg = fleet_sharding.local_block2d(
-                        slot_seg.reshape(R, C), R_loc, C_loc).reshape(-1)
-                    r0 = lax.axis_index(RSU_AXIS) * R_loc
-                    slot_seg_l = jnp.minimum(seg - r0,
-                                             R_loc).astype(jnp.int32)
-                else:
-                    # occupancy-balanced blocks of the compacted axis,
-                    # split over the WHOLE (rsu, vehicle) device grid
-                    members_l = fleet_sharding.local_slice(members, S_loc)
-                    slot_seg_l = fleet_sharding.local_slice(slot_seg,
-                                                            S_loc)
-                idx_slots = idx_all[:, members_l]      # (steps, S_loc, b)
-                args = [carry["edge"], cuts, members_l, slot_seg_l,
-                        idx_slots]
-                if ef:
-                    res_slots = res_base[members_l]    # (S_loc, res)
-                    args.append(res_slots)
-                if fz:
-                    args += [act_v[:, members_l], surv[members_l],
-                             strag[members_l],
-                             carry["stale_num"], carry["stale_den"]]
+                with jax.named_scope("asfl.control"):
+                    members, slot_seg = slot_table_flat(order, seg_v, counts)
+                    if fm is None:
+                        members_l, slot_seg_l = members, slot_seg
+                    elif layout == "dense":
+                        # RSU-aligned tiles: device (i, j)'s slots are its
+                        # R_loc rows x C_loc columns of the padded (R, C)
+                        # grid (with dv == 1 that is exactly the old R_loc-row
+                        # block); localize segment ids and clip the phantom
+                        # segment R onto the local drop row
+                        members_l = fleet_sharding.local_block2d(
+                            members.reshape(R, C), R_loc, C_loc).reshape(-1)
+                        seg = fleet_sharding.local_block2d(
+                            slot_seg.reshape(R, C), R_loc, C_loc).reshape(-1)
+                        r0 = lax.axis_index(RSU_AXIS) * R_loc
+                        slot_seg_l = jnp.minimum(seg - r0,
+                                                 R_loc).astype(jnp.int32)
+                    else:
+                        # occupancy-balanced blocks of the compacted axis,
+                        # split over the WHOLE (rsu, vehicle) device grid
+                        members_l = fleet_sharding.local_slice(members, S_loc)
+                        slot_seg_l = fleet_sharding.local_slice(slot_seg,
+                                                                S_loc)
+                    idx_slots = idx_all[:, members_l]      # (steps, S_loc, b)
+                    args = [carry["edge"], cuts, members_l, slot_seg_l,
+                            idx_slots]
+                    if ef:
+                        res_slots = res_base[members_l]    # (S_loc, res)
+                        args.append(res_slots)
+                    if fz:
+                        args += [act_v[:, members_l], surv[members_l],
+                                 strag[members_l],
+                                 carry["stale_num"], carry["stale_den"]]
                 outs = fleet_round_par(*args)
                 if fz:
                     st_num2, st_den2 = outs[-2], outs[-1]
@@ -1350,133 +1408,139 @@ class SuperStepPrograms:
                 else:
                     edge, ls, w_tot, slot_mask_l = outs
                 ef_mask, ef_members = slot_mask_l, members_l
-                if fz:
-                    # dropouts run only their dstep-batch prefix
-                    cnt = jnp.sum(
-                        jnp.where(sched, dstep, 0)).astype(jnp.float32)
-                else:
-                    # every occupied slot runs exactly `steps` batches
-                    cnt = (jnp.sum(counts) * steps).astype(jnp.float32)
-                if sz:
-                    # StreamBuffer commit (DESIGN.md §14): the cohort's
-                    # round update becomes a PENDING delta in the RSU's
-                    # next free buffer slot; the edge model advances only
-                    # when the buffer holds B deltas and the staleness-
-                    # weighted survivor FedAvg fires.  Runs on the LOCAL
-                    # edge rows (before any gather), so the committed edge
-                    # is what the mesh combine sees.  All state is carry:
-                    # buffer churn is data, never a program signature
-                    edge_old = carry["edge"]
-                    delta = edge - edge_old               # (R_srv, P)
-                    pushed = w_tot > 0.0                  # (R_srv,)
-                    cnt_b = carry["sbuf_cnt"]
-                    slot_oh = (jnp.arange(B, dtype=jnp.int32)[None, :]
-                               == cnt_b[:, None]) & pushed[:, None]
-                    sb = jnp.where(slot_oh[:, :, None], delta[:, None, :],
-                                   carry["sbuf"])
-                    sbw = jnp.where(slot_oh, w_tot[:, None],
-                                    carry["sbuf_w"])
-                    sba = jnp.where(slot_oh, 0, carry["sbuf_age"])
-                    cnt2 = cnt_b + pushed.astype(jnp.int32)
-                    fire = cnt2 >= B                      # (R_srv,)
-                    valid = (jnp.arange(B, dtype=jnp.int32)[None, :]
-                             < cnt2[:, None])
-                    # staleness-weighted survivor FedAvg over the pending
-                    # deltas: weights are merge weight x kernel(age), and
-                    # empty slots fold in as exact +0 through their zero
-                    # weights.  The denominator can sit in (0, 1) under
-                    # polynomial discounts, so the guard is a where
-                    kw = (sbw * streaming.staleness_kernel(
-                        stc.kernel, stc.alpha, sba)
-                        * valid.astype(jnp.float32))
-                    tot_b = jnp.sum(kw, axis=1)           # (R_srv,)
-                    den_b = jnp.where(tot_b > 0.0, tot_b, 1.0)
-                    merged_b = edge_old + jnp.einsum(
-                        "rb,rbp->rp", kw, sb) / den_b[:, None]
-                    edge = jnp.where(fire[:, None], merged_b, edge_old)
-                    # merge telemetry, read BEFORE the post-fire reset
-                    absorbed = jnp.sum(jnp.where(
-                        fire[:, None], sbw * valid, 0.0))
-                    st_stream = jnp.sum(jnp.where(
-                        fire[:, None],
-                        sba.astype(jnp.float32) * valid, 0.0))
-                    fires = jnp.sum(fire.astype(jnp.int32))
-                    occ = jnp.sum(jnp.where(fire, 0, cnt2))
-                    # post-fire: fired buffers clear; survivors age one
-                    # round.  The delta plane itself needs no clear — its
-                    # weights are zero, the exact-+0 convention
-                    sbuf2 = sb
-                    sbw2 = jnp.where(fire[:, None], 0.0, sbw)
-                    sba2 = jnp.where(fire[:, None], 0,
-                                     jnp.where(valid, sba + 1, sba))
-                    cnt3 = jnp.where(fire, 0, cnt2)
-                    if fm is not None and not ragged_par:
-                        # per-RSU scalars sharded over the RSU axis (and
-                        # replicated across the vehicle sub-axis): sum
-                        # home across the RSU shards only
-                        rsu_only = (RSU_AXIS,)
-                        absorbed = fleet_sharding.scalar_allsum(absorbed,
-                                                                rsu_only)
-                        st_stream = fleet_sharding.scalar_allsum(st_stream,
+                with jax.named_scope("asfl.merge"):
+                    if fz:
+                        # dropouts run only their dstep-batch prefix
+                        cnt = jnp.sum(
+                            jnp.where(sched, dstep, 0)).astype(jnp.float32)
+                    else:
+                        # every occupied slot runs exactly `steps` batches
+                        cnt = (jnp.sum(counts) * steps).astype(jnp.float32)
+                    if sz:
+                        # StreamBuffer commit (DESIGN.md §14): the cohort's
+                        # round update becomes a PENDING delta in the RSU's
+                        # next free buffer slot; the edge model advances only
+                        # when the buffer holds B deltas and the staleness-
+                        # weighted survivor FedAvg fires.  Runs on the LOCAL
+                        # edge rows (before any gather), so the committed edge
+                        # is what the mesh combine sees.  All state is carry:
+                        # buffer churn is data, never a program signature
+                        edge_old = carry["edge"]
+                        delta = edge - edge_old               # (R_srv, P)
+                        pushed = w_tot > 0.0                  # (R_srv,)
+                        cnt_b = carry["sbuf_cnt"]
+                        slot_oh = (jnp.arange(B, dtype=jnp.int32)[None, :]
+                                   == cnt_b[:, None]) & pushed[:, None]
+                        sb = jnp.where(slot_oh[:, :, None], delta[:, None, :],
+                                       carry["sbuf"])
+                        sbw = jnp.where(slot_oh, w_tot[:, None],
+                                        carry["sbuf_w"])
+                        sba = jnp.where(slot_oh, 0, carry["sbuf_age"])
+                        cnt2 = cnt_b + pushed.astype(jnp.int32)
+                        fire = cnt2 >= B                      # (R_srv,)
+                        valid = (jnp.arange(B, dtype=jnp.int32)[None, :]
+                                 < cnt2[:, None])
+                        # staleness-weighted survivor FedAvg over the pending
+                        # deltas: weights are merge weight x kernel(age), and
+                        # empty slots fold in as exact +0 through their zero
+                        # weights.  The denominator can sit in (0, 1) under
+                        # polynomial discounts, so the guard is a where
+                        kw = (sbw * streaming.staleness_kernel(
+                            stc.kernel, stc.alpha, sba)
+                            * valid.astype(jnp.float32))
+                        tot_b = jnp.sum(kw, axis=1)           # (R_srv,)
+                        den_b = jnp.where(tot_b > 0.0, tot_b, 1.0)
+                        merged_b = edge_old + jnp.einsum(
+                            "rb,rbp->rp", kw, sb) / den_b[:, None]
+                        edge = jnp.where(fire[:, None], merged_b, edge_old)
+                        # merge telemetry, read BEFORE the post-fire reset
+                        absorbed = jnp.sum(jnp.where(
+                            fire[:, None], sbw * valid, 0.0))
+                        st_stream = jnp.sum(jnp.where(
+                            fire[:, None],
+                            sba.astype(jnp.float32) * valid, 0.0))
+                        fires = jnp.sum(fire.astype(jnp.int32))
+                        occ = jnp.sum(jnp.where(fire, 0, cnt2))
+                        # post-fire: fired buffers clear; survivors age one
+                        # round.  The delta plane itself needs no clear — its
+                        # weights are zero, the exact-+0 convention
+                        sbuf2 = sb
+                        sbw2 = jnp.where(fire[:, None], 0.0, sbw)
+                        sba2 = jnp.where(fire[:, None], 0,
+                                         jnp.where(valid, sba + 1, sba))
+                        cnt3 = jnp.where(fire, 0, cnt2)
+                        if fm is not None and not ragged_par:
+                            # per-RSU scalars sharded over the RSU axis (and
+                            # replicated across the vehicle sub-axis): sum
+                            # home across the RSU shards only
+                            rsu_only = (RSU_AXIS,)
+                            absorbed = fleet_sharding.scalar_allsum(absorbed,
+                                                                    rsu_only)
+                            st_stream = fleet_sharding.scalar_allsum(st_stream,
+                                                                     rsu_only)
+                            fires = fleet_sharding.scalar_allsum(fires,
                                                                  rsu_only)
-                        fires = fleet_sharding.scalar_allsum(fires,
-                                                             rsu_only)
-                        occ = fleet_sharding.scalar_allsum(occ, rsu_only)
-                if fm is not None and layout == "dense":
-                    # per-RSU rows are vehicle-replicated after the
-                    # regrouped segment-sums, so the combine is the same
-                    # RSU-axis gather as the 1-D mesh
-                    ls = lax.all_gather(ls, RSU_AXIS, tiled=True)
-                    w_tot = lax.all_gather(w_tot, RSU_AXIS, tiled=True)
-                    edge_stack = aggregation.gathered_stack(edge,
-                                                            RSU_AXIS)
-                else:
-                    # single device, or ragged mesh: segment-sums were
-                    # already psum'd full-width and the edge is replicated
-                    edge_stack = edge
-            samples = carry["samples"] + w_tot
-            if ef:
-                # masked scatter-ADD of the residual deltas back onto the
-                # fleet plane: padded slots carry a zero delta (their
-                # member index is a clipped duplicate), active slots are
-                # unique per round (a vehicle is served by one RSU), and
-                # under a mesh the psum of per-shard deltas reassembles
-                # the replicated plane — other shards contribute zeros
-                delta = jnp.where(ef_mask[..., None], res_out - res_slots,
-                                  0.0)
-                upd = jnp.zeros_like(res_base).at[
-                    ef_members.reshape(-1)].add(
-                        delta.reshape(-1, delta.shape[-1]))
-                if fm is not None:
-                    # sequential: slots live on RSU-axis shards and the
-                    # vehicle sub-axis replicates them (psum over both
-                    # would multiply by dv); flat schedules: every slot
-                    # lives on exactly one (rsu, vehicle) device
-                    upd = lax.psum(upd,
-                                   (RSU_AXIS,)
-                                   if self.schedule == "sequential"
-                                   else ALL_AXES)
-                wire_res2 = res_base + upd
-                wire_cut2 = jnp.where(sched, cuts,
-                                      carry["wire_cut"]).astype(jnp.int32)
-            handover = sched & (carry["prev"] >= 0) \
-                & (carry["prev"] != serving)
-            prev = jnp.where(serving >= 0, serving, -1).astype(jnp.int32)
-            synced = (rnd + 1) % sync_every == 0
-            merged_global = aggregation.stacked_cloud_merge(
-                edge_stack, samples, carry["global"])
-            carry2 = {
-                "edge": jax.tree.map(
-                    lambda stacked, g: jnp.where(
-                        synced, jnp.broadcast_to(g, stacked.shape), stacked),
-                    edge, merged_global),
-                "samples": jnp.where(synced, jnp.zeros_like(samples),
-                                     samples),
-                "prev": prev,
-                "global": jax.tree.map(
-                    lambda g, old: jnp.where(synced, g, old),
-                    merged_global, carry["global"]),
-            }
+                            occ = fleet_sharding.scalar_allsum(occ, rsu_only)
+                    if fm is not None and layout == "dense":
+                        # per-RSU rows are vehicle-replicated after the
+                        # regrouped segment-sums, so the combine is the same
+                        # RSU-axis gather as the 1-D mesh
+                        ls = lax.all_gather(ls, RSU_AXIS, tiled=True)
+                        w_tot = lax.all_gather(w_tot, RSU_AXIS, tiled=True)
+                        edge_stack = aggregation.gathered_stack(edge,
+                                                                RSU_AXIS)
+                    else:
+                        # single device, or ragged mesh: segment-sums were
+                        # already psum'd full-width and the edge is replicated
+                        edge_stack = edge
+            with jax.named_scope("asfl.merge"):
+                samples = carry["samples"] + w_tot
+            with jax.named_scope("asfl.wire"):
+                if ef:
+                    # masked scatter-ADD of the residual deltas back onto the
+                    # fleet plane: padded slots carry a zero delta (their
+                    # member index is a clipped duplicate), active slots are
+                    # unique per round (a vehicle is served by one RSU), and
+                    # under a mesh the psum of per-shard deltas reassembles
+                    # the replicated plane — other shards contribute zeros
+                    delta = jnp.where(ef_mask[..., None], res_out - res_slots,
+                                      0.0)
+                    upd = jnp.zeros_like(res_base).at[
+                        ef_members.reshape(-1)].add(
+                            delta.reshape(-1, delta.shape[-1]))
+                    if fm is not None:
+                        # sequential: slots live on RSU-axis shards and the
+                        # vehicle sub-axis replicates them (psum over both
+                        # would multiply by dv); flat schedules: every slot
+                        # lives on exactly one (rsu, vehicle) device
+                        upd = lax.psum(upd,
+                                       (RSU_AXIS,)
+                                       if self.schedule == "sequential"
+                                       else ALL_AXES)
+                    wire_res2 = res_base + upd
+                    wire_cut2 = jnp.where(sched, cuts,
+                                          carry["wire_cut"]).astype(jnp.int32)
+            with jax.named_scope("asfl.control"):
+                handover = sched & (carry["prev"] >= 0) \
+                    & (carry["prev"] != serving)
+                prev = jnp.where(serving >= 0, serving, -1).astype(jnp.int32)
+            with jax.named_scope("asfl.merge"):
+                synced = (rnd + 1) % sync_every == 0
+                merged_global = aggregation.stacked_cloud_merge(
+                    edge_stack, samples, carry["global"])
+                carry2 = {
+                    "edge": jax.tree.map(
+                        lambda stacked, g: jnp.where(
+                            synced, jnp.broadcast_to(g, stacked.shape),
+                            stacked),
+                        edge, merged_global),
+                    "samples": jnp.where(synced, jnp.zeros_like(samples),
+                                         samples),
+                    "prev": prev,
+                    "global": jax.tree.map(
+                        lambda g, old: jnp.where(synced, g, old),
+                        merged_global, carry["global"]),
+                }
             if ef:
                 carry2["wire_res"] = wire_res2
                 carry2["wire_cut"] = wire_cut2
@@ -1492,21 +1556,22 @@ class SuperStepPrograms:
                 carry2["sbuf_w"] = sbw2
                 carry2["sbuf_age"] = sba2
                 carry2["sbuf_cnt"] = cnt3
-            ys = {"loss": jnp.sum(ls), "cnt": cnt, "cuts": cuts,
-                  "serving": serving.astype(jnp.int32),
-                  "rates": rates.astype(jnp.float32),
-                  "handover": handover, "counts": counts}
-            if fz:
-                ys.update({"drop": drop, "lost": lost, "strag": strag,
-                           "rsu_down": rsu_down, "dstep": dstep,
-                           "stale_w": stale_w})
-            if cz:
-                ys.update({
-                    "present": jnp.sum(present2.astype(jnp.int32)),
-                    "arrived": jnp.sum(arrived.astype(jnp.int32))})
-            if sz:
-                ys.update({"absorbed": absorbed, "stream_fires": fires,
-                           "buf_occ": occ, "stream_stale": st_stream})
+            with jax.named_scope("asfl.control"):
+                ys = {"loss": jnp.sum(ls), "cnt": cnt, "cuts": cuts,
+                      "serving": serving.astype(jnp.int32),
+                      "rates": rates.astype(jnp.float32),
+                      "handover": handover, "counts": counts}
+                if fz:
+                    ys.update({"drop": drop, "lost": lost, "strag": strag,
+                               "rsu_down": rsu_down, "dstep": dstep,
+                               "stale_w": stale_w})
+                if cz:
+                    ys.update({
+                        "present": jnp.sum(present2.astype(jnp.int32)),
+                        "arrived": jnp.sum(arrived.astype(jnp.int32))})
+                if sz:
+                    ys.update({"absorbed": absorbed, "stream_fires": fires,
+                               "buf_occ": occ, "stream_stale": st_stream})
             return carry2, ys
 
         def superstep(carry, xs):

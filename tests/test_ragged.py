@@ -168,11 +168,14 @@ def test_occupancy_stats_are_honest():
     assert occ_r["layout"] == "ragged" and occ_d["layout"] == "dense"
     mean = float(np.mean([m.n_scheduled for m in hr]))
     assert occ_r["mean_occupied_slots"] == mean
+    assert occ_d["mean_occupied_slots"] == float(
+        np.mean([m.n_scheduled for m in hd]))
     for occ in (occ_r, occ_d):
-        assert 0.0 <= occ["padded_slot_frac"] <= 1.0
-        assert 0.0 < occ["effective_flops_utilization"] <= 1.0
-        assert abs(occ["padded_slot_frac"]
-                   + occ["effective_flops_utilization"] - 1.0) < 1e-9
+        # the padded share is exactly what the occupied slots leave over
+        assert 0.0 <= occ["padded_slot_frac"] < 1.0
+        assert occ["padded_slot_frac"] == pytest.approx(
+            1.0 - occ["mean_occupied_slots"] / occ["executed_slots"],
+            abs=1e-12)
     assert occ_r["executed_slots"] <= occ_d["executed_slots"]
     assert occ_r["owned_plane_frac"] < 1.0 == occ_d["owned_plane_frac"]
 

@@ -357,3 +357,90 @@ def test_staged_mobility_scenarios_run_fused():
     hist = eng.run()
     assert len(hist) == 3
     assert all(np.isfinite(m.loss) for m in hist)
+
+
+# ------------------------------------------- spans, scopes and counters
+def _mlp9_engine(schedule="sequential", wire="none", eval_every=4):
+    """A tiny mlp9 fleet over two K=2 windows; with ``eval_every=4`` only
+    the first window evaluates (cloud syncs every round)."""
+    from repro import api
+    spec = api.ExperimentSpec(
+        model="mlp9",
+        train=api.TrainConfig(rounds=4, local_steps=1, batch_size=8,
+                              optimizer="sgd", lr=1e-2,
+                              eval_every=eval_every,
+                              server_schedule=schedule, wire=wire),
+        fleet=api.FleetConfig(n_vehicles=8, scenario="highway_corridor",
+                              per_vehicle_samples=16, test_samples=300),
+        runtime=api.RuntimeConfig(superstep=2, slot_capacity="tight8"))
+    return api.build_engine(spec)
+
+
+def test_engine_spans_nest_in_the_profiler_trace(tmp_path):
+    """Two windows under jax.profiler: one engine.window per window with
+    its first round as the rnd0 stat, and the engine's host spans nested
+    inside it; engine.evaluate only where an evaluation is due."""
+    from jax.profiler import ProfileData
+    eng = _mlp9_engine()
+    eng.precompile()
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run()
+    path, = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    spans = [(e.start_ns, e.start_ns + e.duration_ns, e.name,
+              dict(e.stats))
+             for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("engine.")]
+    windows = sorted(s for s in spans if s[2] == "engine.window")
+    assert [w[3].get("rnd0") for w in windows] == [0, 2]
+    children = ("engine.stage", "engine.dispatch", "engine.pull",
+                "engine.global_model", "engine.metrics")
+    for i, (a, b, _, _) in enumerate(windows):
+        inside = {n for s, e, n, _ in spans
+                  if a <= s and e <= b and n != "engine.window"}
+        due = {"engine.evaluate"} if i == 0 else set()
+        assert inside == set(children) | due
+    assert sum(n == "engine.evaluate" for _, _, n, _ in spans) == 1
+
+
+@pytest.mark.parametrize("schedule", ["sequential", "parallel"])
+def test_engine_counters_match_a_hand_count(schedule):
+    """After two windows the counters are what the engine did: one
+    super-step per window, the global model's copies (a copy per leaf) or
+    unravel (one program), two eval batches of the 300 test samples in
+    the window that evaluates; a host read per scan output and per eval
+    batch.  reset() zeroes them."""
+    eng = _mlp9_engine(schedule)
+    eng.precompile()
+    assert set(eng.counters.values()) == {0}
+    eng.run()
+    leaves = len(jax.tree.leaves({"units": eng.units, "head": eng.head}))
+    per_window = 1 + (leaves if schedule == "sequential" else 1)
+    ys_leaves = 7       # loss, cnt, cuts, serving, rates, handover, counts
+    assert eng.counters == {
+        "windows": 2, "rounds": 4, "dispatches": 2 * per_window + 2,
+        "host_syncs": 2 * ys_leaves + 2, "evaluations": 1}
+    eng.reset()
+    assert set(eng.counters.values()) == {0}
+
+
+@pytest.mark.parametrize("schedule", ["sequential", "parallel"])
+def test_named_scopes_in_the_lowered_program(schedule):
+    """Every phase scope names ops of the fused window (the wire's in a
+    wire cell), and the eval program carries its own; without debug info
+    the scopes leave no trace in the program text."""
+    from repro.core.fedsim import make_eval_fn
+    eng = _mlp9_engine(schedule, wire="int8")
+    cap = eng._capacity(4)
+    sig = eng.programs.signature(2, cap, eng._total_slots(4))
+    low = eng.programs._build(sig).lower(eng._carry, eng._window_xs(0, 2))
+    text = low.as_text(debug_info=True)
+    for scope in ("asfl.control", "asfl.batch", "asfl.grad", "asfl.wire",
+                  "asfl.server_update", "asfl.client_update",
+                  "asfl.merge"):
+        assert scope in text, scope
+    assert "asfl." not in low.as_text()
+    ev = make_eval_fn(eng.model).lower(
+        eng.units, eng.head, eng.test["images"][:8], eng.test["labels"][:8])
+    assert "asfl.eval" in ev.as_text(debug_info=True)
