@@ -1637,6 +1637,9 @@ class ScenarioEngine:
                                                len(self.clients))
         self._sync_count = 0
         self.history: List[ScenarioRoundMetrics] = []
+        # sequential schedule: per round run since the last reset, the
+        # slot chain's trip count on each RSU-axis shard
+        self.slot_trips: List[np.ndarray] = []
         # what the engine did since the last reset, counted where it
         # happens: windows and rounds run, programs enqueued (super-steps,
         # global-model copies or unravels, eval batches), blocking device
@@ -1717,16 +1720,25 @@ class ScenarioEngine:
         """Occupancy accounting for bench rows (DESIGN.md §12): how much of
         the compiled layout's slot and plane budget the run actually used.
         ``executed_slots`` is per-round slot-compute the program runs
-        (padded grid for dense/sequential, compacted capacity for
-        ragged+parallel); ``mean_occupied_slots`` averages the scheduled
-        counts over the recorded history; ``owned_plane_frac`` is the
-        client-plane prefix fraction (1.0 dense)."""
+        (sequential: slot bodies, RSU rows times the slot chain's trip
+        count summed over RSU-axis shards, averaged over the rounds run
+        since the last reset — the padded grid before any; dense
+        parallel/streaming: the padded grid; ragged parallel/streaming:
+        the compacted capacity); ``mean_occupied_slots`` averages the
+        scheduled counts over the recorded history; ``owned_plane_frac``
+        is the client-plane prefix fraction (1.0 dense)."""
         pg = self.programs
         horizon = max(int(self.cfg.rounds), 1)
         cap = self._capacity(horizon)
         if (self.cfg.server_schedule in ("parallel", "streaming")
                 and pg.layout == "ragged"):
             executed = self._total_slots(horizon)
+        elif pg.schedule == "sequential":
+            rows = pg.n_rsus_padded // (1 if pg.mesh is None
+                                        else pg.mesh.rsu_devices)
+            executed = (rows * float(np.mean(np.sum(self.slot_trips,
+                                                    axis=1)))
+                        if self.slot_trips else float(pg.n_rsus_padded * cap))
         else:
             executed = pg.n_rsus_padded * cap
         occ = [float(m.n_scheduled) for m in self.history]
@@ -1735,7 +1747,7 @@ class ScenarioEngine:
         return {
             "layout": pg.layout,
             "slot_capacity": int(cap),
-            "executed_slots": int(executed),
+            "executed_slots": executed,
             "mean_occupied_slots": mean_occ,
             "padded_slot_frac": float(1.0 - util),
             "owned_plane_frac": float(pg.plane_width
@@ -1862,6 +1874,9 @@ class ScenarioEngine:
                     f"disagree — raise the capacity margin and reset() the "
                     f"engine")
         self._carry = carry
+        if self.programs.schedule == "sequential":
+            self.slot_trips.extend(self.programs.slot_trips(ys["counts"],
+                                                            cap))
         with jax.profiler.TraceAnnotation("engine.global_model"):
             self.units, self.head = self.programs.global_model(carry)
         self.counters["dispatches"] += self.programs.global_model_programs

@@ -31,14 +31,15 @@ This module restructures the hot path around four ideas:
 
 * **Two server schedules, one engine.**  ``sequential`` keeps the source
   paper's §III-B semantics — the RSU updates its shared server-side model
-  on every client batch, in cohort order — as a ``lax.scan`` over slots
-  (client-replica optimizer updates are deferred out of that scan and
-  applied vmapped per local step, which is the identical math since each
-  replica is touched once per step).  ``parallel`` implements the
-  companion paper's parallel server-side execution (arXiv:2405.18707,
-  "Adaptive and Parallel Split Federated Learning in Vehicular Edge
-  Computing"): the RSU consumes the whole cohort's smashed batches at once
-  and takes one |D_n|-weighted mean-gradient step per local step.  The
+  on every client batch, in cohort order — as a loop over slots that
+  stops at the round's fullest RSU (client-replica optimizer updates are
+  deferred out of that loop and applied vmapped per local step, which is
+  the identical math since each replica is touched once per step).
+  ``parallel`` implements the companion paper's parallel server-side
+  execution (arXiv:2405.18707, "Adaptive and Parallel Split Federated
+  Learning in Vehicular Edge Computing"): the RSU consumes the whole
+  cohort's smashed batches at once and takes one |D_n|-weighted
+  mean-gradient step per local step.  The
   parallel schedule has no sequential inner loop at all — every matmul in
   the round batches across the (RSU, slot) axes, which is what lets fleet-
   scale rounds run at the hardware's batched-matmul throughput instead of
@@ -493,6 +494,15 @@ class SuperStepPrograms:
             g = self._unravel(carry["global"])
         return list(g["units"]), g["head"]
 
+    def slot_trips(self, counts, capacity: int) -> np.ndarray:
+        """(rounds, RSU-axis shards) trip counts of the sequential slot
+        chain, from a window's pulled per-RSU ``counts``: each shard runs
+        its slot loop for its fullest RSU's load, at most ``capacity``
+        (the value the program computes from the same counts)."""
+        c = np.minimum(np.asarray(counts), int(capacity))
+        shards = 1 if self.mesh is None else self.mesh.rsu_devices
+        return c.reshape(c.shape[0], shards, -1).max(axis=-1)
+
     # ---- program construction ----------------------------------------
     def _build(self, sig: SuperStepSignature):
         model, cfg, opt = self.model, self.cfg, self.opt
@@ -692,8 +702,8 @@ class SuperStepPrograms:
             """One client batch at one slot: the full unit stack, with the
             units before the slot's cut taken from its replica and the rest
             from the RSU copy.  Only the RSU state mutates here; client
-            gradients stream out as scan outputs and are applied vmapped
-            after the slot scan (each replica is touched once per step, so
+            gradients stream out as loop outputs and are applied vmapped
+            after the slot loop (each replica is touched once per step, so
             deferring its update out of the sequential body is identical
             math at a fraction of the op count)."""
             sv, so = carry
@@ -742,40 +752,145 @@ class SuperStepPrograms:
                     ys = ys + (jnp.where(act, res_new, res_j),)
             return (sv3, so3), ys
 
-        def rsu_round_seq(edge_tree, members, mask, cut_slots, idx_slots,
-                          *extra):
-            """One RSU's whole round (replica init, every local step,
-            unit-wise FedAvg) with the sequential server schedule — vmapped
-            across the RSU axis by the round body.  Params stay in pytree
-            form here: the sequential slot scan is dominated by per-slot
+        def slot_loop(trip, sv, so, xs):
+            """The slot chain of one local step over this shard's RSUs (the
+            leading axis of ``sv``/``so``, then of ``xs`` ahead of the slot
+            axis): ``seq_slot_body``, vmapped over the RSUs, for slots
+            ``[0, trip)`` in order, each slot's outputs written into
+            buffers of the whole slot axis.  ``trip`` is the fullest RSU's
+            load; a slot at or past it is padding in every RSU, and its
+            rows keep what a masked slot would emit — a zero client
+            gradient (discarded by the keep-selects), a zero loss, and the
+            incoming EF residual."""
+            ys0 = (jax.tree.map(jnp.zeros_like, xs[0]),
+                   jnp.zeros(xs[3].shape, jnp.float32))
+            if ef:
+                ys0 = ys0 + (xs[-1],)
+            body_r = jax.vmap(seq_slot_body)
+
+            def body(j, c):
+                st, ys = c
+                st, y = body_r(st, jax.tree.map(
+                    lambda a: lax.dynamic_index_in_dim(a, j, 1, False), xs))
+                return st, jax.tree.map(
+                    lambda b, v: lax.dynamic_update_index_in_dim(b, v, j, 1),
+                    ys, y)
+
+            return lax.fori_loop(0, trip, body, ((sv, so), ys0))
+
+        def seq_merge(edge_tree, sv, cu, members, cut_slots, w_slots,
+                      *fz_planes):
+            """One RSU's unit-wise FedAvg at the end of its round — vmapped
+            across the RSU axis.  ``fz_planes`` (when ``fz``): survivor
+            slots, straggler slots, and the incoming staleness bank."""
+            if fz:
+                surv_slots, strag_slots, st_num_in, st_den_in = fz_planes
+                # survivor weights (DESIGN.md §13): a dropped / lost /
+                # straggling slot's client update folds into the FedAvg as
+                # an exact +0 and the denominator renormalizes over the
+                # survivors.  Server-side contributions stand for every
+                # in-round-active slot — those gradients already landed on
+                # the RSU's own copy
+                w_merge = lengths_f[members] * surv_slots
+                w_bank = lengths_f[members] * strag_slots
+            else:
+                w_merge = w_slots
+            w_total = jnp.sum(w_merge)
+            den = jnp.maximum(w_total, 1.0)
+            merged, st_num_out, st_den_out = [], [], []
+            for u in range(U):
+                if u >= CU:
+                    # no replica exists past the bucket: every slot's
+                    # weight lands on the server copy, so the unit-wise
+                    # FedAvg collapses to (w_total * sv) / den — the value
+                    # the dense path computes through its all-zero client
+                    # weights
+                    merged.append(jax.tree.map(
+                        lambda s, ref: jnp.where(
+                            w_total > 0.0,
+                            ((w_total * s.astype(jnp.float32))
+                             / den).astype(ref.dtype), ref),
+                        sv["units"][u], edge_tree["units"][u]))
+                    continue
+                w_u = w_slots * (cut_slots > u)
+                if fz:
+                    # survivor-weighted numerator + last round's staleness
+                    # bank at the discount; den_u can sit in (0, 1) when
+                    # only discounted bank weight remains, so the guard is
+                    # a where, not a max
+                    num = aggregation.survivor_weighted_sum(
+                        cu[u], w_u, surv_slots)
+                    swu = w_total - jnp.sum(w_merge * (cut_slots > u))
+                    den_u = w_total + disc * st_den_in[u]
+                    den_safe = jnp.where(den_u > 0.0, den_u, 1.0)
+                    num = jax.tree.map(
+                        lambda nm, s, st: (nm + swu * s.astype(jnp.float32)
+                                           + disc * st),
+                        num, sv["units"][u], st_num_in[u])
+                    merged.append(jax.tree.map(
+                        lambda nm, ref: jnp.where(
+                            den_u > 0.0,
+                            (nm / den_safe).astype(ref.dtype), ref),
+                        num, edge_tree["units"][u]))
+                    # this round's bank: straggler replicas fold with the
+                    # same exact-+0 masking and merge NEXT round
+                    st_num_out.append(aggregation.survivor_weighted_sum(
+                        cu[u], w_u, strag_slots))
+                    st_den_out.append(jnp.sum(w_bank * (cut_slots > u)))
+                    continue
+                swu = w_total - jnp.sum(w_u)
+                num = aggregation.stacked_weighted_sum(cu[u], w_u)
+                num = jax.tree.map(
+                    lambda nm, s: nm + swu * s.astype(jnp.float32),
+                    num, sv["units"][u])
+                merged.append(jax.tree.map(
+                    lambda nm, ref: jnp.where(
+                        w_total > 0.0, (nm / den).astype(ref.dtype), ref),
+                    num, edge_tree["units"][u]))
+            out = {"units": merged, "head": sv["head"]}
+            if fz:
+                return out, w_total, st_num_out, jnp.stack(st_den_out)
+            return out, w_total
+
+        def rsu_round_seq(trip, edge_tree, members, mask, cut_slots,
+                          idx_steps, *extra):
+            """The whole round (replica init, every local step, unit-wise
+            FedAvg) of this shard's RSUs with the sequential server
+            schedule.  Every argument leads with the RSU axis, except
+            ``idx_steps`` (steps, R, C, batch) and the per-step activity,
+            which lead with the local step; ``trip`` is the slot chain's
+            trip count, shared by the RSUs.  The per-RSU pieces are
+            vmapped, and the slot loop runs outside them, so it indexes a
+            slot of the (RSU, slot) arrays directly.  Params stay in pytree
+            form here: the sequential slot loop is dominated by per-slot
             tree math, and ravelling in/out of the flat plane per round
             measurably loses to plain trees on CPU.
 
             ``extra`` packs the statically gated optional planes, in order:
             the EF residual slots (when ``ef``), then the fault planes
-            (when ``fz``): per-step slot activity (steps, C), survivor
+            (when ``fz``): per-step slot activity (steps, R, C), survivor
             slots, straggler slots, and the incoming staleness bank."""
             i = 0
             if ef:
                 res_slots = extra[0]
                 i = 1
             if fz:
-                (act_steps, surv_slots, strag_slots,
-                 st_num_in, st_den_in) = extra[i:]
+                act_steps, *fz_planes = extra[i:]
             sv = {"units": list(edge_tree["units"]),
                   "head": edge_tree["head"]}
             with jax.named_scope("asfl.server_update"):
-                so = opt.init(sv)
+                so = jax.vmap(opt.init)(sv)
             # ragged layout: replicas exist only for the CU units a cut can
             # reach — the per-slot memory and deferred-update math shrink
             # to the owned prefix
             with jax.named_scope("asfl.client_update"):
                 cu = [jax.tree.map(
-                    lambda a: jnp.broadcast_to(a, (C,) + a.shape), u)
+                    lambda a: jnp.broadcast_to(
+                        a[:, None], (a.shape[0], C) + a.shape[1:]), u)
                     for u in edge_tree["units"][:CU]]
-                co = jax.vmap(opt.init)(cu)
+                co = jax.vmap(jax.vmap(opt.init))(cu)
             with jax.named_scope("asfl.merge"):
-                w_slots = lengths_f[members] * mask      # (C,)
+                w_slots = lengths_f[members] * mask      # (R, C)
             if not fz:
                 with jax.named_scope("asfl.client_update"):
                     keep_cu = [mask & (cut_slots > u) for u in range(CU)]
@@ -791,18 +906,16 @@ class SuperStepPrograms:
                 else:
                     sv, so, cu, co = carry
                     xs = (cu, members, cut_slots, act_s, idx_s)
-                # the scan's own work (each slot's replica sliced in, its
+                # the loop's own work (each slot's replica sliced in, its
                 # client gradient stacked out) is the deferred client update
                 with jax.named_scope("asfl.client_update"):
-                    (sv, so), ys = lax.scan(
-                        seq_slot_body, (sv, so), xs,
-                        unroll=2 if C >= 64 else 1)
+                    (sv, so), ys = slot_loop(trip, sv, so, xs)
                 if ef:
                     g_cu, losses, res = ys
                 else:
                     g_cu, losses = ys
                 with jax.named_scope("asfl.client_update"):
-                    upd_c, co2 = jax.vmap(opt.update)(g_cu, co, cu)
+                    upd_c, co2 = jax.vmap(jax.vmap(opt.update))(g_cu, co, cu)
                     cu2 = optim.apply_updates(cu, upd_c)
                     # a dropout's replica stops updating at its drop step
                     # (per-step keep); the zero-fault path keeps the
@@ -814,86 +927,23 @@ class SuperStepPrograms:
                     co = _sel_list_state(co2, co, keep_s,
                                          jnp.asarray(act_s))
                     out = (sv, so, cu, co, res) if ef else (sv, so, cu, co)
-                    return out, (jnp.sum(losses),
-                                 jnp.sum(act_s.astype(jnp.float32)))
+                    return out, (jnp.sum(losses, axis=1),
+                                 jnp.sum(act_s.astype(jnp.float32), axis=1))
 
             init = (sv, so, cu, co, res_slots) if ef else (sv, so, cu, co)
-            xs_steps = (idx_slots, act_steps) if fz else idx_slots
+            xs_steps = (idx_steps, act_steps) if fz else idx_steps
             (sv, so, cu, co, *res_t), (ls, cs) = lax.scan(
                 step_body, init, xs_steps,
                 unroll=min(steps, 2))
             with jax.named_scope("asfl.merge"):
-                if fz:
-                    # survivor weights (DESIGN.md §13): a dropped / lost /
-                    # straggling slot's client update folds into the FedAvg as
-                    # an exact +0 and the denominator renormalizes over the
-                    # survivors.  Server-side contributions stand for every
-                    # in-round-active slot — those gradients already landed on
-                    # the RSU's own copy
-                    w_merge = lengths_f[members] * surv_slots
-                    w_bank = lengths_f[members] * strag_slots
-                else:
-                    w_merge = w_slots
-                w_total = jnp.sum(w_merge)
-                den = jnp.maximum(w_total, 1.0)
-                merged, st_num_out, st_den_out = [], [], []
-                for u in range(U):
-                    if u >= CU:
-                        # no replica exists past the bucket: every slot's
-                        # weight lands on the server copy, so the unit-wise
-                        # FedAvg collapses to (w_total * sv) / den — the value
-                        # the dense path computes through its all-zero client
-                        # weights
-                        merged.append(jax.tree.map(
-                            lambda s, ref: jnp.where(
-                                w_total > 0.0,
-                                ((w_total * s.astype(jnp.float32))
-                                 / den).astype(ref.dtype), ref),
-                            sv["units"][u], edge_tree["units"][u]))
-                        continue
-                    w_u = w_slots * (cut_slots > u)
-                    if fz:
-                        # survivor-weighted numerator + last round's staleness
-                        # bank at the discount; den_u can sit in (0, 1) when
-                        # only discounted bank weight remains, so the guard is
-                        # a where, not a max
-                        num = aggregation.survivor_weighted_sum(
-                            cu[u], w_u, surv_slots)
-                        swu = w_total - jnp.sum(w_merge * (cut_slots > u))
-                        den_u = w_total + disc * st_den_in[u]
-                        den_safe = jnp.where(den_u > 0.0, den_u, 1.0)
-                        num = jax.tree.map(
-                            lambda nm, s, st: (nm + swu * s.astype(jnp.float32)
-                                               + disc * st),
-                            num, sv["units"][u], st_num_in[u])
-                        merged.append(jax.tree.map(
-                            lambda nm, ref: jnp.where(
-                                den_u > 0.0,
-                                (nm / den_safe).astype(ref.dtype), ref),
-                            num, edge_tree["units"][u]))
-                        # this round's bank: straggler replicas fold with the
-                        # same exact-+0 masking and merge NEXT round
-                        st_num_out.append(aggregation.survivor_weighted_sum(
-                            cu[u], w_u, strag_slots))
-                        st_den_out.append(jnp.sum(w_bank * (cut_slots > u)))
-                        continue
-                    swu = w_total - jnp.sum(w_u)
-                    num = aggregation.stacked_weighted_sum(cu[u], w_u)
-                    num = jax.tree.map(
-                        lambda nm, s: nm + swu * s.astype(jnp.float32),
-                        num, sv["units"][u])
-                    merged.append(jax.tree.map(
-                        lambda nm, ref: jnp.where(
-                            w_total > 0.0, (nm / den).astype(ref.dtype), ref),
-                        num, edge_tree["units"][u]))
-                out = {"units": merged, "head": sv["head"]}
-                rets = [out, jnp.sum(ls), jnp.sum(cs), w_total]
+                merged = jax.vmap(seq_merge)(
+                    edge_tree, sv, cu, members, cut_slots, w_slots,
+                    *(fz_planes if fz else ()))
+                rets = [merged[0], jnp.sum(ls, axis=0), jnp.sum(cs, axis=0),
+                        merged[1]]
                 if ef:
                     rets.append(res_t[0])
-                if fz:
-                    rets.append(st_num_out)
-                    rets.append(jnp.stack(st_den_out))
-                return tuple(rets)
+                return tuple(rets) + tuple(merged[2:])
 
         # ---- parallel schedule (arXiv:2405.18707: the RSUs execute the
         # cohorts' server-side passes in parallel and take one weighted
@@ -1324,20 +1374,22 @@ class SuperStepPrograms:
                             mask, R_loc, axes=(RSU_AXIS,))
                     else:
                         members_l, mask_l = members, mask
-                    idx_rsu = jnp.moveaxis(idx_all[:, members_l], 1, 0)
+                    # the slot chain runs for this shard's fullest RSU
+                    # (its occupied slots, at most C): slots past it are
+                    # padding in every local RSU row
+                    trip = jnp.max(jnp.sum(mask_l, axis=1, dtype=jnp.int32))
                     cut_slots = cuts[members_l]            # (R_loc, C)
                     args = [carry["edge"], members_l, mask_l, cut_slots,
-                            idx_rsu]
+                            idx_all[:, members_l]]  # (steps, R_loc, C, b)
                     if ef:
                         res_slots = res_base[members_l]    # (R_loc, C, res)
                         args.append(res_slots)
                     if fz:
-                        act_rsu = jnp.moveaxis(act_v[:, members_l], 1, 0) \
-                            & mask_l[:, None, :]           # (R_loc, steps, C)
-                        args += [act_rsu, surv[members_l] & mask_l,
+                        args += [act_v[:, members_l] & mask_l,
+                                 surv[members_l] & mask_l,
                                  strag[members_l] & mask_l,
                                  carry["stale_num"], carry["stale_den"]]
-                outs = jax.vmap(rsu_round_seq)(*args)
+                outs = rsu_round_seq(trip, *args)
                 if fz:
                     st_num2, st_den2 = outs[-2], outs[-1]
                     outs = outs[:-2]

@@ -180,6 +180,30 @@ def test_occupancy_stats_are_honest():
     assert occ_r["owned_plane_frac"] < 1.0 == occ_d["owned_plane_frac"]
 
 
+def test_sequential_occupancy_counts_slot_bodies():
+    """Sequential schedule: executed slots are the slot bodies the program
+    ran — RSU rows times the slot chain's trip count, the fullest RSU's
+    load of each round — so a fleet with padding executes fewer than the
+    padded (R, C) grid."""
+    n = 8
+    sc = S.make_scenario("highway_corridor", n, seed=2)
+    clients, test = _vector_clients(n)
+    cfg = _cfg("ragged", server_schedule="sequential", rounds=4, superstep=2)
+    eng = ScenarioEngine(TinyMLP(), clients, test, cfg, sc,
+                         cloud_sync_every=2)
+    hist = eng.run()
+    trips = [int(t[0]) for t in eng.slot_trips]   # one RSU-axis shard
+    assert trips == [max(m.rsu_loads) for m in hist]
+    occ = eng.occupancy_stats()
+    rows = eng.programs.n_rsus_padded
+    assert occ["executed_slots"] == rows * float(np.mean(trips))
+    assert occ["executed_slots"] < rows * eng._capacity(4)
+    assert occ["padded_slot_frac"] == pytest.approx(
+        1.0 - occ["mean_occupied_slots"] / occ["executed_slots"], abs=1e-12)
+    eng.reset()
+    assert eng.slot_trips == []
+
+
 def test_compacted_overflow_raises():
     """A signature planned for fewer slots than the fleet occupies must
     fail loudly (truncated cohorts would train silently wrong)."""

@@ -108,6 +108,70 @@ def test_rsu_loads_follow_the_trace():
     assert all(sum(m.rsu_loads) == m.n_scheduled for m in hist)
 
 
+# ------------------------------------------- the slot chain's trip count
+def _uneven_engines(**kw):
+    """{(slot_capacity, K): engine} over an 8-vehicle highway fleet on 4
+    RSUs whose fullest RSU holds 3 or 4 vehicles by round: the sequential
+    slot chain stops short of both capacities (pow2 4, tight8 8), at a
+    trip count that changes between rounds."""
+    sc = S.make_scenario("highway_corridor", 8, seed=2)
+    clients, test = _vector_clients(8)
+    return {(cap, k): ScenarioEngine(
+        TinyMLP(), clients, test, _cfg(slot_capacity=cap, superstep=k, **kw),
+        sc, cloud_sync_every=2)
+        for cap in ("pow2", "tight8") for k in (1, ROUNDS)}
+
+
+@pytest.mark.parametrize("planes", [
+    dict(wire="topk_int8"),
+    dict(fault_dropout=0.2, fault_upload_loss=0.1, fault_straggler=1e-7,
+         fault_rsu_outage=0.3, fault_seed=1)], ids=["ef_wire", "faults"])
+def test_skipped_slots_are_exact_noops(planes):
+    """Slots past the fullest RSU's load are skipped, not computed and
+    masked: pow2 and tight8 capacity, K-fused and per-round, train
+    bit-identical params, losses and EF residual planes."""
+    engines = _uneven_engines(**planes)
+    hists = {key: eng.run() for key, eng in engines.items()}
+    ref = engines["tight8", ROUNDS]
+    trips = [int(t[0]) for t in ref.slot_trips]
+    assert max(trips) < ref._capacity(ROUNDS)
+    assert min(trips) < engines["pow2", ROUNDS]._capacity(ROUNDS)
+    assert len(set(trips)) > 1
+    for key, eng in engines.items():
+        assert [[int(x) for x in t] for t in eng.slot_trips] == \
+            [[t] for t in trips], key
+        jax.tree.map(np.testing.assert_array_equal, _params(ref),
+                     _params(eng))
+        np.testing.assert_array_equal(
+            [m.loss for m in hists["tight8", ROUNDS]],
+            [m.loss for m in hists[key]])
+        if "wire_res" in ref._carry:
+            for plane in ("wire_res", "wire_cut"):
+                np.testing.assert_array_equal(np.asarray(ref._carry[plane]),
+                                              np.asarray(eng._carry[plane]))
+
+
+def test_slot_chain_runs_every_occupied_slot():
+    """With lr = 0 the model stays at its initial weights, so each round's
+    loss is the initial model's mean loss over every scheduled vehicle's
+    batches, computed here on the host: a chain that stopped before the
+    fullest RSU's last vehicle would leave that vehicle's terms out."""
+    eng = _uneven_engines(lr=0.0)["tight8", ROUNDS]
+    hist = eng.run()
+    model = TinyMLP()
+    units, head = model.init(jax.random.PRNGKey(eng.cfg.seed))
+    steps = eng.cfg.local_steps
+    for m in hist:
+        idx = np.asarray(fleet_batch_indices_traced(
+            jax.random.fold_in(jax.random.PRNGKey(eng.cfg.seed), m.round),
+            eng.lengths, steps, eng.cfg.batch_size))
+        losses = [model.head_loss(head, model.apply_units(
+            units, c.images[idx[s, v]], 0), c.labels[idx[s, v]])[0]
+            for v, c in enumerate(eng.clients) if m.cuts[v] > 0
+            for s in range(steps)]
+        np.testing.assert_allclose(m.loss, np.mean(losses), rtol=1e-5)
+
+
 # ------------------------------------------------------- donation safety
 def test_donated_carries_never_reused():
     """The super-step donates its carry: old carry buffers must be deleted,
@@ -444,3 +508,52 @@ def test_named_scopes_in_the_lowered_program(schedule):
     ev = make_eval_fn(eng.model).lower(
         eng.units, eng.head, eng.test["images"][:8], eng.test["labels"][:8])
     assert "asfl.eval" in ev.as_text(debug_info=True)
+
+
+# sha256 of the lowered StableHLO, without debug info, of the parallel and
+# streaming windows of ``_mlp9_engine``: these schedules never run the
+# sequential slot chain, so a change to it leaves their programs as they
+# were
+FLAT_SCHEDULE_HLO = {
+    "parallel":
+        "738d0a78b7f5577dec5af0642c245cdddf520476a048ffbcca7e8caf0d76d1ab",
+    "streaming":
+        "502eba971d0ecc7ba2b5c28fed3efcbb3a98d72d300040c61113b835aa654a7b",
+}
+
+
+def _window_program(eng):
+    cap = eng._capacity(4)
+    sig = eng.programs.signature(2, cap, eng._total_slots(4))
+    return eng.programs._build(sig), (eng._carry, eng._window_xs(0, 2)), cap
+
+
+@pytest.mark.parametrize("schedule", sorted(FLAT_SCHEDULE_HLO))
+def test_flat_schedules_lower_to_pinned_programs(schedule):
+    import hashlib
+    fn, args, _ = _window_program(_mlp9_engine(schedule))
+    text = fn.lower(*args).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        FLAT_SCHEDULE_HLO[schedule]
+
+
+def test_sequential_slot_loop_has_no_static_trip_count():
+    """The slot chain is a while loop bounded by the round's fullest RSU,
+    not a scan over the slot capacity C (C = 8 here, distinct from the
+    window's K = 2 rounds and its single local step)."""
+    fn, args, cap = _window_program(_mlp9_engine("sequential"))
+
+    def eqns(jaxpr):
+        for e in jaxpr.eqns:
+            yield e
+            for p in e.params.values():
+                for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                    sub = getattr(sub, "jaxpr", sub)     # ClosedJaxpr
+                    if hasattr(sub, "eqns"):
+                        yield from eqns(sub)
+
+    prims = list(eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+    scan_lengths = [e.params["length"] for e in prims
+                    if e.primitive.name == "scan"]
+    assert cap == 8 and 8 not in scan_lengths, scan_lengths
+    assert any(e.primitive.name == "while" for e in prims)
