@@ -51,12 +51,14 @@ class ModelEntry:
     """A federated model: lazy ``UnitModel`` builder + the fleet-data
     builder that produces compatible client shards.
 
-    ``make_data(n_vehicles, per_vehicle, n_test, seed)`` must be a pure
-    function of its arguments (benchmark warm re-runs and the api-vs-direct
-    parity tests rely on identical shards)."""
+    ``make_data(n_vehicles, per_vehicle, n_test, seed, model=None)`` must
+    be a pure function of its arguments (benchmark warm re-runs and the
+    api-vs-direct parity tests rely on identical shards); ``model`` is the
+    built model, whose sequence length and vocabulary an LM's shards
+    take."""
     name: str
     build: Callable[..., Any]
-    make_data: Callable[[int, int, int, int], Tuple[list, dict]]
+    make_data: Callable[..., Tuple[list, dict]]
     n_units: int
     description: str = ""
 
@@ -85,7 +87,7 @@ def _build_resnet(**kw):
     return ResNetModel(**kw)
 
 
-def _resnet_data(n_vehicles, per_vehicle, n_test, seed):
+def _resnet_data(n_vehicles, per_vehicle, n_test, seed, model=None):
     from repro.data.pipeline import make_federated_data
     return make_federated_data(seed, n_train=per_vehicle * n_vehicles,
                                n_test=n_test, n_clients=n_vehicles)
@@ -96,14 +98,14 @@ def _build_mlp9(**kw):
     return MLPUnitModel(**kw)
 
 
-def _mlp9_data(n_vehicles, per_vehicle, n_test, seed):
+def _mlp9_data(n_vehicles, per_vehicle, n_test, seed, model=None):
     from repro.models.mlp_unit import make_mlp_fleet_data
     return make_mlp_fleet_data(n_vehicles, per_vehicle, seed=seed,
                                n_test=n_test)
 
 
 def make_lm_fleet_data(n_vehicles: int, per_vehicle: int, n_test: int,
-                       seed: int, vocab_size: int, seq_len: int = 8):
+                       seed: int, vocab_size: int, seq_len: int):
     """Synthetic next-token shards for the LM UnitModels: ``images`` are
     token ids (n, seq), ``labels`` the shifted next tokens — the fedsim
     batch convention (core/lm_unit.py)."""
@@ -128,20 +130,29 @@ def make_lm_fleet_data(n_vehicles: int, per_vehicle: int, n_test: int,
 
 def _arch_model_entry(arch_id: str) -> ModelEntry:
     from repro.configs import get_config
+    from repro.models.transformer import segments_of
     cfg = get_config(arch_id)
-    reduced = cfg.reduced()
     # unit granularity (core/lm_unit.py): embedding + one unit per period
-    n_units = 1 + reduced.n_periods + (1 if reduced.tail else 0)
+    n_units = 1 + sum(n for _, n in segments_of(cfg.reduced()))
 
-    def build(reduced: bool = True):
+    def build(reduced: bool = True, seq_len: int = 8, **lora):
+        """The reduced (default) or published config.  LoRA keywords
+        (``n_layers``, ``experts_held``, ``vocab_rows``, ``lora_rank``,
+        ``lora_alpha``, ``base_seed``) build split LoRA fine-tuning over
+        a frozen base (``SplitLoRAUnitModel``) instead of full training."""
         from repro.configs import get_config
-        from repro.core.lm_unit import TransformerUnitModel
+        from repro.core.lm_unit import SplitLoRAUnitModel, TransformerUnitModel
         c = get_config(arch_id)
-        return TransformerUnitModel(c.reduced() if reduced else c)
+        c = c.reduced() if reduced else c
+        if lora:
+            return SplitLoRAUnitModel(c, seq_len=seq_len, **lora)
+        return TransformerUnitModel(c, seq_len=seq_len)
 
-    def make_data(n_vehicles, per_vehicle, n_test, seed):
+    def make_data(n_vehicles, per_vehicle, n_test, seed, model=None):
+        m = model or build()
         return make_lm_fleet_data(n_vehicles, per_vehicle, n_test, seed,
-                                  vocab_size=reduced.vocab_size)
+                                  vocab_size=m.vocab_size,
+                                  seq_len=m.seq_len)
 
     return ModelEntry(
         name=arch_id, build=build, make_data=make_data, n_units=n_units,

@@ -113,7 +113,7 @@ def build_engine(spec: ExperimentSpec):
     model = entry.build(**spec.model_kwargs)
     f = spec.fleet
     clients, test = entry.make_data(f.n_vehicles, f.per_vehicle_samples,
-                                    f.test_samples, f.data_seed)
+                                    f.test_samples, f.data_seed, model=model)
     cfg = spec.to_sim_config()
     mesh = fleet_sharding.from_config(cfg, spec.engine_kind,
                                       fleet_size=f.n_vehicles)

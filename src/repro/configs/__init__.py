@@ -6,7 +6,7 @@ from typing import Dict, List
 
 from repro.configs.base import (  # noqa: F401  (re-exported)
     ArchConfig, MoEConfig, MLAConfig, SSMConfig, RGLRUConfig, ShapeConfig,
-    INPUT_SHAPES, pad_vocab,
+    INPUT_SHAPES, YarnConfig, pad_vocab,
 )
 
 _MODULES = {

@@ -34,6 +34,9 @@ class MoEConfig:
     d_ff_expert: int = 0      # expert hidden dim (0 -> use arch d_ff)
     capacity_factor: float = 1.25
     aux_loss_weight: float = 0.01
+    # renormalise the top-k gates to sum to one (DeepSeek-V2:
+    # ``norm_topk_prob: false`` keeps the raw softmax probabilities)
+    norm_topk_prob: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,6 +45,18 @@ class MLAConfig:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 publishes it
+    (``rope_scaling`` of its config.json)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,14 +93,16 @@ class ArchConfig:
     vocab_size: int
     head_dim: int = 0         # 0 -> d_model // n_heads
     # Layer pattern: tuple of layer-type ids forming one repeating period.
-    # The stack = pattern * n_periods + tail.  n_layers must equal
-    # len(pattern) * n_periods + len(tail).
+    # The stack = lead + pattern * n_periods + tail.  n_layers must equal
+    # len(lead) + len(pattern) * n_periods + len(tail).
     pattern: Tuple[str, ...] = (ATTN,)
     tail: Tuple[str, ...] = ()
+    lead: Tuple[str, ...] = ()   # layers before the first period
     # Attention details
     qk_norm: bool = False
     window: int = 0           # sliding window size for ATTN_LOCAL layers
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnConfig] = None
     pos: str = "rope"         # rope | sinusoidal
     mlp_variant: str = "swiglu"  # swiglu | geglu | gelu
     logit_softcap: float = 0.0
@@ -117,14 +134,15 @@ class ArchConfig:
     @property
     def layer_types(self) -> Tuple[str, ...]:
         n_periods = self.n_periods
-        return tuple(self.pattern) * n_periods + tuple(self.tail)
+        return (tuple(self.lead) + tuple(self.pattern) * n_periods
+                + tuple(self.tail))
 
     @property
     def n_periods(self) -> int:
-        body = self.n_layers - len(self.tail)
+        body = self.n_layers - len(self.tail) - len(self.lead)
         assert body % len(self.pattern) == 0, (
-            f"{self.name}: {self.n_layers} layers, pattern {self.pattern}, "
-            f"tail {self.tail} do not tile")
+            f"{self.name}: {self.n_layers} layers, lead {self.lead}, pattern "
+            f"{self.pattern}, tail {self.tail} do not tile")
         return body // len(self.pattern)
 
     def param_count(self) -> int:
@@ -162,7 +180,7 @@ class ArchConfig:
         rglru = None
         if self.rglru is not None:
             rglru = dataclasses.replace(self.rglru, d_rnn=0)
-        n_tail = len(self.tail)
+        n_tail = len(self.tail) + len(self.lead)
         # keep 1-2 periods so every layer type in the pattern is exercised
         n_layers = len(self.pattern) + n_tail
         return dataclasses.replace(

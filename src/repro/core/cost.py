@@ -164,7 +164,7 @@ def arch_profile(cfg: ArchConfig, seq: int, param_bytes_per: int = 2
     def layer_params(kind: str) -> int:
         # reuse the analytic counter via a 1-layer pseudo-config
         import dataclasses as dc
-        one = dc.replace(cfg, n_layers=1, pattern=(kind,), tail=())
+        one = dc.replace(cfg, n_layers=1, pattern=(kind,), tail=(), lead=())
         base = T.count_params(one)
         emb = one.padded_vocab * one.d_model * (
             one.n_codebooks if one.frontend == "audio" else 1)

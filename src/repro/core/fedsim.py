@@ -416,11 +416,14 @@ def make_eval_fn(model: UnitModel):
     # cycle model -> fn -> model is ordinary gc-collectable garbage)
     fn = getattr(model, "_eval_fn", None)
     if fn is None:
+        # a frozen base (split LoRA) is the program's last argument, bound
+        # to the model inside the trace; other models take none
         @jax.jit
-        def fn(units, head, x, y):
+        def fn(units, head, x, y, *frozen):
             with jax.named_scope("asfl.eval"):
-                feats = model.apply_units(units, x, 0)
-                logits = model.head_predict(head, feats)
+                m = model.bind(*frozen) if frozen else model
+                feats = m.apply_units(units, x, 0)
+                logits = m.head_predict(head, feats)
                 return jnp.sum(jnp.argmax(logits, -1) == y)
         model._eval_fn = fn
     return fn
@@ -432,13 +435,15 @@ EVAL_BATCH = 256
 def evaluate(model: UnitModel, units, head, test: Dict[str, jnp.ndarray],
              batch: int = EVAL_BATCH) -> float:
     fn = make_eval_fn(model)
+    frozen = ((model.frozen_params(),) if hasattr(model, "frozen_params")
+              else ())
     n = test["labels"].shape[0]
     correct = []
     total = 0
     for i in range(0, n, batch):
         x = test["images"][i:i + batch]
         y = test["labels"][i:i + batch]
-        correct.append(fn(units, head, x, y))
+        correct.append(fn(units, head, x, y, *frozen))
         total += int(np.prod(y.shape))
     # host ints: an eager sum of device scalars compiles an add per
     # placement
@@ -1644,9 +1649,12 @@ class ScenarioEngine:
         # happens: windows and rounds run, programs enqueued (super-steps,
         # global-model copies or unravels, eval batches), blocking device
         # reads (pulled scan outputs, eval batch counts), evaluations
+        # (and the model's own counter, when the program sums one per
+        # round: SplitLoRAUnitModel's held expert rows)
         self.counters: Dict[str, int] = dict.fromkeys(
             ("windows", "rounds", "dispatches", "host_syncs",
-             "evaluations"), 0)
+             "evaluations") + ((self.programs.aux_counter,)
+                               if self.programs.aux_counter else ()), 0)
 
     # ---- staging ------------------------------------------------------
     def _nb_ep(self) -> Tuple[int, int]:
@@ -1836,7 +1844,8 @@ class ScenarioEngine:
             xs = self._xs_stage.take((rnd0, k),
                                      lambda: self._window_xs(rnd0, k))
         with jax.profiler.TraceAnnotation("engine.dispatch"):
-            carry, ys = fn(self._carry, xs)        # async dispatch
+            carry, ys = fn(self._carry, xs,        # async dispatch
+                           *self.programs.frozen_args)
         self.counters["dispatches"] += 1
         # double-buffered staging (DESIGN.md §14): while the dispatched
         # window trains on device, build the NEXT window's batch/mobility
@@ -1874,6 +1883,9 @@ class ScenarioEngine:
                     f"disagree — raise the capacity margin and reset() the "
                     f"engine")
         self._carry = carry
+        if self.programs.aux_counter:
+            self.counters[self.programs.aux_counter] += int(
+                ys[self.programs.aux_counter].sum())
         if self.programs.schedule == "sequential":
             self.slot_trips.extend(self.programs.slot_trips(ys["counts"],
                                                             cap))

@@ -138,6 +138,13 @@ slot ages — the FedBuff policy).  Both planes are gated at Python level on
 ``StreamConfig.churning`` / the schedule flag, so the zero-streaming
 program is byte-identical, and all state is carry — presence/buffer churn
 is data, never a program signature (tests/test_streaming.py).
+
+Frozen base (split LoRA fine-tuning, ``SplitLoRAUnitModel``): a model with
+``frozen_params()`` trains only its adapters on the flat plane, FedAvg and
+Adam; its frozen base enters every program as a third, non-donated
+argument and is bound to the model (``model.bind``) inside the trace.
+Closed over, a multi-GB base would be compiled in as a constant.  Models
+without one trace the identical two-argument program.
 """
 from __future__ import annotations
 
@@ -397,6 +404,24 @@ class SuperStepPrograms:
                        else streaming.StreamConfig())
         self.cz = self.stream.churning
         self.sz = self.schedule == "streaming"
+        # frozen base (split LoRA fine-tuning): a pytree every program
+        # takes as a non-donated argument, bound to the model inside the
+        # trace — as a closed-over constant a multi-GB base would be
+        # compiled into the program.  None for models without one: their
+        # programs take no such argument
+        self.frozen = (model.frozen_params()
+                       if hasattr(model, "frozen_params") else None)
+        if self.frozen is not None:
+            if self.wire != "none":
+                raise ValueError("a frozen-base model trains without a "
+                                 "wire scheme (wire='none')")
+            if mesh is not None:
+                self.frozen = mesh.replicate(self.frozen)
+        self.frozen_args = () if self.frozen is None else (self.frozen,)
+        # a model's per-batch counter (its head loss's aux output), summed
+        # per round into the outputs by the sequential schedule
+        self.aux_counter = (getattr(model, "aux_counter", None)
+                            if self.schedule == "sequential" else None)
 
     def flatten(self, units, head) -> jnp.ndarray:
         return ravel_pytree({"units": list(units), "head": head})[0]
@@ -530,6 +555,7 @@ class SuperStepPrograms:
         wire, ef, wire_k = self.wire, self.ef, self.wire_k
         bshapes, res_size = self.boundary_shapes, self.res_size
         wire_units = self.wire_units
+        cr = self.aux_counter is not None
         # fault-plane statics (DESIGN.md §13): gated at Python level on
         # `fz` throughout — zero-fault configs trace the identical program
         fc, fz = self.faults, self.fz
@@ -725,7 +751,7 @@ class SuperStepPrograms:
                     + [sv["units"][u] for u in range(CU, U)]
             with jax.named_scope("asfl.grad"):
                 if wire == "none":
-                    (loss, _), (g_units, g_head) = jax.value_and_grad(
+                    (loss, aux), (g_units, g_head) = jax.value_and_grad(
                         loss_fn, argnums=(0, 1), has_aux=True)(
                             eff, sv["head"], x, y)
                 else:
@@ -747,6 +773,8 @@ class SuperStepPrograms:
                        "head": _select(act, sv2["head"], sv["head"])}
                 so3 = _sel_server_state(so2, so, keep_s, act)
                 ys = (list(g_units[:CU]), jnp.where(act, loss, 0.0))
+                if cr:
+                    ys = ys + (jnp.where(act, aux, 0),)
             if ef:
                 with jax.named_scope("asfl.wire"):
                     ys = ys + (jnp.where(act, res_new, res_j),)
@@ -760,10 +788,12 @@ class SuperStepPrograms:
             buffers of the whole slot axis.  ``trip`` is the fullest RSU's
             load; a slot at or past it is padding in every RSU, and its
             rows keep what a masked slot would emit — a zero client
-            gradient (discarded by the keep-selects), a zero loss, and the
-            incoming EF residual."""
+            gradient (discarded by the keep-selects), a zero loss, a zero
+            count, and the incoming EF residual."""
             ys0 = (jax.tree.map(jnp.zeros_like, xs[0]),
                    jnp.zeros(xs[3].shape, jnp.float32))
+            if cr:
+                ys0 = ys0 + (jnp.zeros(xs[3].shape, jnp.int32),)
             if ef:
                 ys0 = ys0 + (xs[-1],)
             body_r = jax.vmap(seq_slot_body)
@@ -910,10 +940,9 @@ class SuperStepPrograms:
                 # client gradient stacked out) is the deferred client update
                 with jax.named_scope("asfl.client_update"):
                     (sv, so), ys = slot_loop(trip, sv, so, xs)
+                g_cu, losses = ys[:2]
                 if ef:
-                    g_cu, losses, res = ys
-                else:
-                    g_cu, losses = ys
+                    res = ys[-1]
                 with jax.named_scope("asfl.client_update"):
                     upd_c, co2 = jax.vmap(jax.vmap(opt.update))(g_cu, co, cu)
                     cu2 = optim.apply_updates(cu, upd_c)
@@ -927,12 +956,15 @@ class SuperStepPrograms:
                     co = _sel_list_state(co2, co, keep_s,
                                          jnp.asarray(act_s))
                     out = (sv, so, cu, co, res) if ef else (sv, so, cu, co)
-                    return out, (jnp.sum(losses, axis=1),
-                                 jnp.sum(act_s.astype(jnp.float32), axis=1))
+                    per_step = (jnp.sum(losses, axis=1),
+                                jnp.sum(act_s.astype(jnp.float32), axis=1))
+                    if cr:
+                        per_step = per_step + (jnp.sum(ys[2]),)
+                    return out, per_step
 
             init = (sv, so, cu, co, res_slots) if ef else (sv, so, cu, co)
             xs_steps = (idx_steps, act_steps) if fz else idx_steps
-            (sv, so, cu, co, *res_t), (ls, cs) = lax.scan(
+            (sv, so, cu, co, *res_t), (ls, cs, *counted) = lax.scan(
                 step_body, init, xs_steps,
                 unroll=min(steps, 2))
             with jax.named_scope("asfl.merge"):
@@ -943,7 +975,10 @@ class SuperStepPrograms:
                         merged[1]]
                 if ef:
                     rets.append(res_t[0])
-                return tuple(rets) + tuple(merged[2:])
+                rets += list(merged[2:])
+                if cr:
+                    rets.append(jnp.sum(counted[0]))
+                return tuple(rets)
 
         # ---- parallel schedule (arXiv:2405.18707: the RSUs execute the
         # cohorts' server-side passes in parallel and take one weighted
@@ -1390,6 +1425,11 @@ class SuperStepPrograms:
                                  strag[members_l] & mask_l,
                                  carry["stale_num"], carry["stale_den"]]
                 outs = rsu_round_seq(trip, *args)
+                if cr:
+                    n_counted = outs[-1]
+                    if fm is not None:
+                        n_counted = lax.psum(n_counted, RSU_AXIS)
+                    outs = outs[:-1]
                 if fz:
                     st_num2, st_den2 = outs[-2], outs[-1]
                     outs = outs[:-2]
@@ -1613,6 +1653,8 @@ class SuperStepPrograms:
                       "serving": serving.astype(jnp.int32),
                       "rates": rates.astype(jnp.float32),
                       "handover": handover, "counts": counts}
+                if cr:
+                    ys[self.aux_counter] = n_counted
                 if fz:
                     ys.update({"drop": drop, "lost": lost, "strag": strag,
                                "rsu_down": rsu_down, "dstep": dstep,
@@ -1628,6 +1670,16 @@ class SuperStepPrograms:
 
         def superstep(carry, xs):
             return lax.scan(round_body, carry, xs)
+
+        if self.frozen is not None:
+            scan_rounds = superstep
+
+            def superstep(carry, xs, frozen):
+                # every closure above reads `model`: bind it to this
+                # trace's frozen argument before the rounds are traced
+                nonlocal model
+                model = self.model.bind(frozen)
+                return scan_rounds(carry, xs)
 
         if fm is not None:
             # ragged + parallel replicates the edge stack (the mesh splits
@@ -1654,7 +1706,8 @@ class SuperStepPrograms:
                 for k in ("sbuf", "sbuf_w", "sbuf_age", "sbuf_cnt"):
                     carry_spec[k] = edge_spec
             superstep = jax.shard_map(superstep, mesh=fm.mesh,
-                                      in_specs=(carry_spec, PSpec()),
+                                      in_specs=(carry_spec, PSpec())
+                                      + (PSpec(),) * len(self.frozen_args),
                                       out_specs=(carry_spec, PSpec()),
                                       check_vma=False)
         return jax.jit(superstep, donate_argnums=(0,))
@@ -1716,8 +1769,9 @@ class SuperStepPrograms:
             a = jnp.asarray(a)
             return jax.ShapeDtypeStruct(a.shape, a.dtype)
 
-        compiled = self._build(sig).lower(jax.tree.map(sds, carry),
-                                          jax.tree.map(sds, xs)).compile()
+        compiled = self._build(sig).lower(
+            jax.tree.map(sds, carry), jax.tree.map(sds, xs),
+            *jax.tree.map(sds, self.frozen_args)).compile()
         self._programs[sig] = compiled
         if self.schedule != "sequential" and self._unravel is None:
             self._unravel = jax.jit(self.unravel).lower(

@@ -6,6 +6,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Params = Dict[str, Any]
 
@@ -88,19 +89,53 @@ def mlp_flops(d_model: int, d_ff: int, variant: str) -> int:
 # positions
 # --------------------------------------------------------------------------
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               inv_freq=None, scale: float = 1.0) -> jnp.ndarray:
     """x: (..., seq, n_heads, head_dim) or (..., seq, head_dim);
-    positions: broadcastable to (..., seq)."""
+    positions: broadcastable to (..., seq).  ``inv_freq`` (head_dim // 2,)
+    replaces the plain ``theta`` frequencies and ``scale`` multiplies cos
+    and sin (YaRN, :func:`yarn_inv_freq`)."""
     head_dim = x.shape[-1]
     half = head_dim // 2
-    freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freqs = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     angles = positions.astype(jnp.float32)[..., None] * freqs  # (..., seq, half)
     if x.ndim == angles.ndim + 2:  # x has a head axis between seq and dim
         angles = angles[..., None, :]
-    sin, cos = jnp.sin(angles), jnp.cos(angles)
+    sin, cos = jnp.sin(angles) * scale, jnp.cos(angles) * scale
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term: 0.1 * mscale * ln(factor) + 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, rs) -> np.ndarray:
+    """(dim // 2,) YaRN rotary frequencies as DeepSeek-V2 computes them
+    (``DeepseekV2YarnRotaryEmbedding``): the extrapolated ``theta``
+    frequencies above the correction range, ``factor``-interpolated ones
+    below it, and a linear ramp between.  ``rs`` is a
+    :class:`~repro.configs.base.YarnConfig`."""
+    pos = theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    extra, inter = 1.0 / pos, 1.0 / (rs.factor * pos)
+
+    def corr(rotations):
+        return (dim * math.log(rs.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr(rs.beta_fast)), 0)
+    high = min(math.ceil(corr(rs.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp          # 1 where the frequency is extrapolated
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
 
 
 def sinusoidal_pos(positions: jnp.ndarray, d_model: int) -> jnp.ndarray:

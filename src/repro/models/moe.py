@@ -9,6 +9,13 @@
   and the router gates the sum.  Exact (no capacity drops), cheap when
   T * E * d_ff is small — the right trade at one-token decode.
 
+* **Held experts, dropless** (:func:`moe_held`): one chip's share of an
+  expert-parallel layer.  The router scores all experts; the chip computes
+  only its held experts' part of the result, for every token routed to
+  them (no capacity, no drops), as a grouped matmul over the assigned
+  rows (``lax.ragged_dot``).  The experts are frozen there: the grouped
+  matmul carries the input gradient only (split LoRA fine-tuning).
+
 DBRX: 16 routed top-4.  DeepSeek-V2-Lite: 64 routed top-6 + 2 shared.
 Aux load-balance loss follows Switch/GShard.
 """
@@ -55,8 +62,9 @@ def _route(p: Params, cfg: ArchConfig, xt: jnp.ndarray):
     logits = xt.astype(jnp.float32) @ p["router"]
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, expert_idx = jax.lax.top_k(probs, m.top_k)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    if m.norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
     onehot = jax.nn.one_hot(expert_idx, m.n_experts, dtype=jnp.float32)
     frac_tokens = jnp.mean(jnp.sum(onehot, axis=1), axis=0)
     frac_probs = jnp.mean(probs, axis=0)
@@ -153,3 +161,88 @@ def moe_flops(cfg: ArchConfig) -> int:
     m, d, ff = cfg.moe, cfg.d_model, _expert_ff(cfg)
     per_expert = 2 * 3 * d * ff
     return m.top_k * per_expert + m.n_shared * per_expert + 2 * d * m.n_experts
+
+
+# --------------------------------------------------------------------------
+# held experts, dropless (one chip's share of an expert-parallel layer)
+# --------------------------------------------------------------------------
+
+@jax.custom_batching.custom_vmap
+def _ragged(x, w, sizes):
+    return jax.lax.ragged_dot(x, w, sizes)
+
+
+@_ragged.def_vmap
+def _ragged_vmap(axis_size, in_batched, x, w, sizes):
+    """Batched rows over shared experts (an RSU axis vmapped over the same
+    frozen weights, which are never batched): one grouped matmul per batch
+    row, in a loop, instead of ``ragged_dot``'s batching rule, which wants
+    the weights batched too (a copy of every held expert per row)."""
+    xb, _, sb = in_batched
+    bc = lambda a, b: a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+    return jax.lax.map(lambda a: _ragged(a[0], w, a[1]),
+                       (bc(x, xb), bc(sizes, sb))), True
+
+
+@jax.custom_vjp
+def grouped_matmul(x, w, sizes):
+    """(m, k) rows sorted by group, (g, k, n) frozen weights, (g,) group
+    sizes -> (m, n); rows past ``sum(sizes)`` come out zero.  Only ``x``
+    has a gradient: differentiating with respect to ``w`` raises."""
+    return _ragged(x, w, sizes)
+
+
+def _gmm_fwd(x, w, sizes):
+    if w.perturbed:
+        raise TypeError("grouped_matmul holds frozen expert weights: "
+                        "they have no gradient")
+    return _ragged(x.value, w.value, sizes.value), (w.value, sizes.value)
+
+
+def _gmm_bwd(res, dy):
+    w, sizes = res
+    if isinstance(dy, jax.custom_derivatives.SymbolicZero):
+        return None, None, None
+    return _ragged(dy, jnp.swapaxes(w, 1, 2), sizes), None, None
+
+
+grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd, symbolic_zeros=True)
+
+
+def moe_held(p: Params, cfg: ArchConfig, x: jnp.ndarray, first: int
+             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """x (..., d) -> (y, rows): the part of a routed-expert layer that the
+    held experts ``[first, first + n_held)`` give, plus the shared experts.
+
+    ``p["router"]`` scores all ``cfg.moe.n_experts``; ``p["wi_gate"]``,
+    ``p["wi_up"]`` and ``p["wo"]`` lead with the ``n_held`` experts held.
+    Every (token, expert) pair of the top-k that lands on a held expert is
+    computed — no capacity, no dropped token — as one grouped matmul over
+    the pairs sorted by expert; pairs on absent experts sort past the last
+    group and add nothing.  Gates are the router's softmax probabilities,
+    renormalised over the top-k only when ``norm_topk_prob``.  ``rows`` is
+    the number of (token, held expert) pairs computed."""
+    m = cfg.moe
+    k, n_held = m.top_k, p["wi_gate"].shape[0]
+    shape = x.shape
+    xt = x.reshape(-1, shape[-1])
+    with jax.named_scope("asfl.moe_route"):
+        _, gates, idx, _, _ = _route(p, cfg, xt)
+        local = idx - first
+        held = (local >= 0) & (local < n_held)               # (t, k)
+        group = jnp.where(held, local, n_held).reshape(-1)   # (t*k,)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.sum(group[None, :] == jnp.arange(n_held)[:, None],
+                        axis=1).astype(jnp.int32)
+        rows = xt[order // k]
+    with jax.named_scope("asfl.moe_experts"):
+        h = jax.nn.silu(grouped_matmul(rows, p["wi_gate"], sizes)) \
+            * grouped_matmul(rows, p["wi_up"], sizes)
+        out = grouped_matmul(h, p["wo"], sizes)
+        out = out[jnp.argsort(order)].reshape(idx.shape + (shape[-1],))
+        y = jnp.einsum("tk,tkd->td", jnp.where(held, gates, 0.0).astype(
+            out.dtype), jnp.where(held[..., None], out, 0.0))
+    if m.n_shared:
+        with jax.named_scope("asfl.moe_shared"):
+            y = y + L.mlp(p["shared"], xt, "swiglu")
+    return y.reshape(shape), jnp.sum(held, dtype=jnp.int32)

@@ -127,7 +127,8 @@ def init_layer_cache(cfg: ArchConfig, kind: str, batch: int, capacity: int,
 # --------------------------------------------------------------------------
 
 def segments_of(cfg: ArchConfig) -> List[Tuple[Tuple[str, ...], int]]:
-    segs = [(tuple(cfg.pattern), cfg.n_periods)]
+    segs = [(tuple(cfg.lead), 1)] if cfg.lead else []
+    segs.append((tuple(cfg.pattern), cfg.n_periods))
     if cfg.tail:
         segs.append((tuple(cfg.tail), 1))
     return segs

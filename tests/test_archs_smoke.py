@@ -33,7 +33,8 @@ def _batch(cfg, key, seq=S):
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_reduced_forward_shapes_and_train_step(arch):
     cfg = get_config(arch).reduced()
-    assert cfg.d_model <= 256 and cfg.n_layers <= len(cfg.pattern) + len(cfg.tail)
+    assert cfg.d_model <= 256 and cfg.n_layers <= (
+        len(cfg.lead) + len(cfg.pattern) + len(cfg.tail))
     if cfg.moe:
         assert cfg.moe.n_experts <= 4
     key = jax.random.PRNGKey(0)
